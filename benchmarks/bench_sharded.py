@@ -14,13 +14,12 @@ are a software-overhead proxy (collective count, not bandwidth); the
 shape of the curve — AMPER flat-ish, PER paying the global cumsum — is
 the recorded signal.
 
-Run standalone (forces its own 8 host devices, must be a fresh process):
+Run standalone (forces 8 host devices on a CPU host before JAX starts):
 
     python -m benchmarks.bench_sharded --json BENCH_sharded.json
 
-``benchmarks/run.py`` invokes exactly that as a subprocess, because
-XLA_FLAGS must be set before the first jax init and the parent process
-has usually initialised jax already.
+``benchmarks/run.py`` runs the same :func:`run` in its own process,
+after forcing the host devices itself.
 """
 from __future__ import annotations
 
@@ -31,7 +30,8 @@ import sys
 DEVICE_COUNT = 8
 
 
-def _force_host_devices(n: int = DEVICE_COUNT) -> None:
+def force_host_devices(n: int = DEVICE_COUNT) -> None:
+    """Give the CPU backend ``n`` devices; a no-op once JAX has started."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -39,7 +39,7 @@ def _force_host_devices(n: int = DEVICE_COUNT) -> None:
 
 
 def _ensure_repro_importable() -> None:
-    """Subprocess-friendly: put <repo>/src on sys.path if needed."""
+    """Standalone-friendly: put <repo>/src on sys.path if needed."""
     try:
         import repro  # noqa: F401
     except ModuleNotFoundError:
@@ -51,7 +51,7 @@ def _ensure_repro_importable() -> None:
 def run(shard_counts=(1, 2, 4, 8), n: int = 1 << 16, batch: int = 256,
         verbose: bool = True):
     """Times sample() and update() for both sharded samplers per shard
-    count.  Requires enough devices (call via main() / subprocess)."""
+    count; shard counts above the visible device count are skipped."""
     import jax
     import jax.numpy as jnp
 
@@ -96,7 +96,7 @@ def main(argv=None) -> None:
     ap.add_argument("--shards", default="1,2,4,8")
     args = ap.parse_args(argv)
 
-    _force_host_devices()
+    force_host_devices()
     _ensure_repro_importable()
     shard_counts = tuple(int(s) for s in args.shards.split(","))
     n = 1 << 13 if args.quick else 1 << 16
@@ -118,5 +118,5 @@ def main(argv=None) -> None:
 
 if __name__ == "__main__":
     # must run before any jax import in this process
-    _force_host_devices()
+    force_host_devices()
     main()

@@ -7,16 +7,18 @@ persists each section's rows as machine-readable ``BENCH_<section>.json``
 is recorded across commits.  Roofline tables come from the dry-run
 artifacts: see benchmarks/roofline.py and EXPERIMENTS.md.
 
-The ``sharded`` section runs in a subprocess: it must force 8 host
-devices via XLA_FLAGS before first jax init, which this parent process
-has already performed by the time the section runs.
+Everything runs in this one process: an accelerator belongs to the
+process that first touches JAX, so a JAX child started later would fail
+or hang.  The ``sharded`` section needs several devices; on a CPU host
+:func:`main` forces 8 host devices via XLA_FLAGS before JAX is first
+imported (the flag does not affect an accelerator backend), and the
+section sweeps the shard counts the visible devices allow.
 """
 from __future__ import annotations
 
 import argparse
 import contextlib
 import os
-import subprocess
 import sys
 import traceback
 
@@ -38,6 +40,12 @@ def main() -> None:
                          "registry for the whole run and writes one "
                          "snapshot per section (spans, counters) there")
     args = ap.parse_args()
+    from benchmarks import bench_sharded
+
+    bench_sharded.force_host_devices()  # before the first JAX import
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     failures = []
     written = []
@@ -80,21 +88,6 @@ def main() -> None:
             exporter.write_snapshot(registry.snapshot(),
                                     extra={"section": name})
 
-    def sharded_subprocess():
-        """Fresh process so XLA_FLAGS can force the 8-device host mesh."""
-        json_path = os.path.join(args.out, "BENCH_sharded.json")
-        cmd = [sys.executable, "-m", "benchmarks.bench_sharded",
-               "--json", json_path] + (["--quick"] if args.quick else [])
-        out = subprocess.run(cmd, capture_output=True, text=True,
-                             timeout=1800, cwd=os.path.dirname(
-                                 os.path.dirname(os.path.abspath(__file__))))
-        print(out.stdout, end="")
-        if out.returncode != 0:
-            raise RuntimeError(f"bench_sharded failed:\n{out.stderr[-2000:]}")
-        if os.path.exists(json_path):
-            written.append(json_path)
-        return None  # the child already wrote its own json
-
     from benchmarks import (bench_replay, bench_runtime, bench_samplers,
                             bench_storage, bench_vector_env, fig4_latency,
                             fig7_sampling_error, fig9_hw_latency,
@@ -130,7 +123,8 @@ def main() -> None:
         steps=120))
     section("storage", lambda: bench_storage.run(
         sizes=(10_000,) if args.quick else (10_000, 100_000)))
-    section("sharded", sharded_subprocess)
+    section("sharded", lambda: bench_sharded.run(
+        n=1 << 13 if args.quick else 1 << 16))
 
     if exporter:
         exporter.close()

@@ -23,6 +23,7 @@ import argparse
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Telemetry
 from repro.rl.dqn import DQNConfig
 from repro.rl.envs import available_envs
@@ -63,6 +64,7 @@ ap.add_argument("--metrics-out", default=None,
                      "probes) to this path; Prometheus text lands next "
                      "to it as <path>.prom")
 args = ap.parse_args()
+enable_compile_cache()
 
 REPLAY_RATIO = 4  # frames per learner step, in units of num_envs
 

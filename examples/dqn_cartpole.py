@@ -14,6 +14,7 @@ import time
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rl.dqn import DQNConfig, make_dqn
 from repro.rl.envs import available_envs
 
@@ -30,6 +31,7 @@ ap.add_argument("--num-envs", type=int, default=1,
 ap.add_argument("--replay", type=int, default=2000)
 ap.add_argument("--seed", type=int, default=0)
 args = ap.parse_args()
+enable_compile_cache()
 
 frames = args.steps * args.num_envs
 print(f"agent={args.agent} n_step={args.n_step}")

@@ -67,6 +67,21 @@ class AmperConfig(NamedTuple):
     fr_mode: str = "broadcast"
 
 
+FR_MODES = ("broadcast", "interval", "window", "kernel", "fused")
+KNN_MODES = ("sort", "bisect", "hist")
+
+
+def check_modes(cfg: AmperConfig) -> None:
+    """Raise on an ``fr_mode`` / ``knn_mode`` no path implements (an
+    unknown mode must not fall through to another search silently)."""
+    if cfg.fr_mode not in FR_MODES:
+        raise ValueError(f"unknown fr_mode {cfg.fr_mode!r} "
+                         f"(available: {FR_MODES})")
+    if cfg.knn_mode not in KNN_MODES:
+        raise ValueError(f"unknown knn_mode {cfg.knn_mode!r} "
+                         f"(available: {KNN_MODES})")
+
+
 class CspResult(NamedTuple):
     """Stream-compacted candidate set of priorities."""
 
@@ -140,6 +155,7 @@ def build_csp_fr(pq: jax.Array, valid: jax.Array, key: jax.Array,
         non-zero priority.
       key: PRNG key for the group representatives.
     """
+    check_modes(cfg)
     if cfg.fr_mode in ("kernel", "fused"):
         # "fused" only differs on the *sampling* path (AmperSampler.sample
         # dispatches the whole draw as one kernel); explicit CSP builds
@@ -403,6 +419,7 @@ class AmperSampler:
     def __init__(self, cfg: AmperConfig, variant: str = "fr"):
         if variant not in ("fr", "k"):
             raise ValueError(f"unknown AMPER variant: {variant!r}")
+        check_modes(cfg)
         self.cfg = cfg
         self.variant = variant
 
@@ -457,12 +474,14 @@ class AmperSampler:
         the reference would, and indices come out bit-identical.
         """
         from repro.kernels import ops as kops  # deferred: kernels are optional
+        from repro.kernels.amper_sample import MAX_FRAC_BITS
 
         cfg = self.cfg
-        if cfg.frac_bits > 24:
+        if cfg.frac_bits > MAX_FRAC_BITS:
             raise ValueError(
-                f"fr_mode='fused' needs frac_bits <= 24 (one-hot f32 "
-                f"gathers are exact below 2^24), got {cfg.frac_bits}")
+                f"fr_mode='fused' needs frac_bits <= {MAX_FRAC_BITS} "
+                f"(one-hot f32 gathers are exact below 2^24), got "
+                f"{cfg.frac_bits}")
         kv, kroll = jax.random.split(kcsp)
         v_rep = group_representatives(kv, cfg)
         lo, hi = fr_intervals(v_rep, cfg)

@@ -215,7 +215,9 @@ def _build_amper_k(capacity: int, **kw) -> Sampler:
 
 def _default_mesh():
     """1-D mesh over every visible device (the zero-config sharded case)."""
-    return jax.make_mesh((jax.device_count(),), ("data",))
+    from repro.launch.mesh import make_replay_mesh
+
+    return make_replay_mesh()
 
 
 @register_sampler("amper-fr-sharded")

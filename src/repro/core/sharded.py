@@ -56,27 +56,25 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 import repro.core.quantize as qz
 from repro.core.amper import (AmperConfig, AmperSampler, AmperState,
                               fr_intervals, fr_queries, fr_radii,
                               group_representatives)
-from repro.distributed.sharding import axis_size
 
 
 def _flat_axis_index(axis_names: Sequence[str]) -> jax.Array:
     """Row-major linear index of this shard over possibly-multiple mesh axes."""
     idx = jnp.int32(0)
     for name in axis_names:
-        idx = idx * axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 
 def _n_shards(axis_names: Sequence[str]) -> jax.Array:
     n = jnp.int32(1)
     for name in axis_names:
-        n = n * axis_size(name)
+        n = n * jax.lax.axis_size(name)
     return n
 
 
@@ -203,11 +201,11 @@ def sharded_sample_fr(mesh: Mesh, cfg: AmperConfig, batch: int,
     axes = resolve_axes(mesh, axis_names)
     local_cap = _local_csp_capacity(mesh, axes, cfg, local_csp_capacity)
     spec = P(axes)
-    return shard_map(
+    return jax.shard_map(
         _fr_sample_body(cfg, batch, axes, local_cap), mesh=mesh,
         in_specs=(spec, spec, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -245,9 +243,9 @@ def sharded_sample_per(mesh: Mesh, batch: int,
     """
     axes = resolve_axes(mesh, axis_names)
     spec = P(axes)
-    return shard_map(_per_sample_body(batch, axes), mesh=mesh,
-                     in_specs=(spec, P()), out_specs=P(),
-                     check_rep=False)
+    return jax.shard_map(_per_sample_body(batch, axes), mesh=mesh,
+                         in_specs=(spec, P()), out_specs=P(),
+                         check_vma=False)
 
 
 def repartition(sampler, state):
@@ -316,12 +314,12 @@ class ShardedAmperSampler(AmperSampler):
     def _sample_fn(self, batch: int):
         fn = self._sample_fns.get(batch)
         if fn is None:
-            fn = shard_map(
+            fn = jax.shard_map(
                 _fr_sample_body(self.cfg, batch, self.axis_names,
                                 self.local_csp_capacity),
                 mesh=self.mesh,
                 in_specs=(self.spec, self.spec, P()), out_specs=P(),
-                check_rep=False)
+                check_vma=False)
             self._sample_fns[batch] = fn
         return fn
 
@@ -343,9 +341,9 @@ class ShardedAmperSampler(AmperSampler):
             v_rep = group_representatives(kq, self.cfg)
             return _local_match_fr(pq_local, valid_local, v_rep, self.cfg)
 
-        fn = shard_map(body, mesh=self.mesh,
-                       in_specs=(self.spec, self.spec, P()),
-                       out_specs=self.spec, check_rep=False)
+        fn = jax.shard_map(body, mesh=self.mesh,
+                           in_specs=(self.spec, self.spec, P()),
+                           out_specs=self.spec, check_vma=False)
         return fn(state.pq, state.valid, key)
 
 
@@ -398,9 +396,9 @@ class ShardedPERSampler:
     def _sample_fn(self, batch: int):
         fn = self._sample_fns.get(batch)
         if fn is None:
-            fn = shard_map(_per_sample_body(batch, self.axis_names),
-                           mesh=self.mesh, in_specs=(self.spec, P()),
-                           out_specs=P(), check_rep=False)
+            fn = jax.shard_map(_per_sample_body(batch, self.axis_names),
+                               mesh=self.mesh, in_specs=(self.spec, P()),
+                               out_specs=P(), check_vma=False)
             self._sample_fns[batch] = fn
         return fn
 

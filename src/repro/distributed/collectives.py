@@ -20,9 +20,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.distributed.sharding import axis_size
 from repro.train import optimizer as opt_mod
 
 
@@ -34,7 +32,7 @@ def psum_int8_mean(grads: Any, axis: str) -> Any:
     is the narrow tensor, which is what the collective-bytes analysis
     counts).
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g):
         q, s = opt_mod.quantize_int8(g.astype(jnp.float32))
@@ -69,12 +67,12 @@ def grad_fn_with_pod_sync(grad_fn: Callable, mesh, param_specs: Any,
         return jax.tree.map(
             lambda t: jax.lax.pmean(t.astype(jnp.float32), "pod"), g)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(inner_param_specs, inner_batch_specs),
         out_specs=inner_param_specs,
-        check_rep=False,
-        auto=frozenset(a for a in mesh.axis_names if a != "pod"),
+        check_vma=False,
+        axis_names=frozenset({"pod"}),  # "data"/"model" stay Auto
     )
 
 

@@ -101,16 +101,6 @@ def active_rules() -> Optional[ShardingRules]:
     return getattr(_ACTIVE, "rules", None)
 
 
-def axis_size(name: str) -> jax.Array:
-    """Number of shards along one mesh axis, from inside shard_map/pmap.
-
-    ``jax.lax.axis_size`` does not exist in the pinned JAX version;
-    ``psum(1, name)`` is the portable equivalent (costless: XLA folds a
-    constant all-reduce to the static mesh extent).
-    """
-    return jax.lax.psum(jax.numpy.int32(1), name)
-
-
 def logical_constraint(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
     """with_sharding_constraint by logical axes; no-op without a context."""
     rules = active_rules()

@@ -26,10 +26,18 @@ index order therefore reproduces the compacted buffer's answer without
 materialising it, including under capacity truncation (the draw is
 ``bits % min(total, csp_capacity)``, always a valid cyclic rank).
 
-The one-hot row/lane gathers run as f32 matmuls (MXU-friendly); they are
-exact for integers below 2^24, which bounds ``frac_bits <= 24`` (the
+The one-hot row/lane gathers run as f32 matmuls at
+``Precision.HIGHEST`` (MXU-friendly); a single bf16 pass would round the
+gathered row offsets above 256, so full f32 is what keeps them exact
+for integers below 2^24, which bounds ``frac_bits <= 24`` (the
 default).  ``interpret=True`` off-TPU executes the identical program in
-Python, so CPU CI pins the exact kernel logic.
+Python, so CPU CI pins the exact kernel logic; the Mosaic lowering is
+pinned by ``tests/test_tpu_compile.py``.
+
+Mosaic constraints the layout follows: scalars (range bounds, shift,
+key words, counters, stats) live in SMEM, iotas are integer (cast to
+f32 where a float index is needed), and every VMEM block is
+(8, 128)-aligned or the whole array.
 """
 from __future__ import annotations
 
@@ -40,7 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import DEFAULT_BLOCK_ROWS, LANES
+from repro.kernels.common import DEFAULT_BLOCK_ROWS, LANES, smem_spec
 
 MAX_FRAC_BITS = 24  # one-hot f32 matmul gathers are exact below 2^24
 
@@ -67,29 +75,31 @@ def _threefry2x32(k0, k1, x0, x1):
     return x0, x1
 
 
-def counter_bits(key_data: jax.Array, j: jax.Array, n: jax.Array) -> jax.Array:
+def counter_bits(key_data, j: jax.Array) -> jax.Array:
     """``jax.random.bits(key, (n,), uint32)`` evaluated at positions ``j``.
 
-    jax's threefry layout runs counters ``0..n-1`` (odd n padded with one
-    trailing 0) split into two halves (x0 = first half, x1 = second); the
-    output is the concatenation of the two cipher outputs.  Each lane here
-    recomputes its own pair, so the whole draw is a map — no slicing, no
-    cross-lane traffic, safe inside a kernel at any alignment.
-
-    ``j`` may be any uint32 array of positions < n; ``n`` is a traced
-    scalar (int32).  Positions >= n return the padded-counter stream.
+    Under jax's partitionable threefry layout, bit ``j`` of a 1-D draw is
+    ``o0 ^ o1`` of ``threefry2x32(key, (0, j))`` for any length ``n``, so
+    each lane computes its own word: the whole draw is a map — no
+    slicing, no cross-lane traffic, safe inside a kernel at any
+    alignment.  ``key_data`` is any pair indexable as ``[0]``/``[1]``.
     """
-    k0 = key_data[0]
-    k1 = key_data[1]
-    n = n.astype(jnp.uint32)
-    h = (n + (n & jnp.uint32(1))) >> jnp.uint32(1)  # ceil(n/2)
-    j = j.astype(jnp.uint32)
-    in_lo = j < h
-    p = jnp.where(in_lo, j, j - h)
-    x0 = p
-    x1 = jnp.where(h + p < n, h + p, jnp.uint32(0))  # odd-n trailing pad
-    o0, o1 = _threefry2x32(k0, k1, x0, x1)
-    return jnp.where(in_lo, o0, o1)
+    o0, o1 = _threefry2x32(key_data[0], key_data[1], jnp.uint32(0),
+                           j.astype(jnp.uint32))
+    return o0 ^ o1
+
+
+def split_key(key_data, i: int):
+    """Subkey ``i`` of ``jax.random.split(key)``: the word pair
+    ``threefry2x32(key, (0, i))`` (partitionable layout)."""
+    return _threefry2x32(key_data[0], key_data[1], jnp.uint32(0),
+                         jnp.uint32(i))
+
+
+def _gather_dot(a, b):
+    """f32 matmul exact for integer operands below 2^24 (one-hot gathers)."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def _match_tile(p, valid, lo_ref, hi_ref, m: int):
@@ -100,11 +110,49 @@ def _match_tile(p, valid, lo_ref, hi_ref, m: int):
     return sel & valid
 
 
+def _rank_select_tile(sel, rank, base, b, block_rows: int):
+    """Members of ordinary rank ``rank`` that fall in this tile.
+
+    ``sel`` is the tile's (block_rows, 128) match mask, ``base`` the member
+    count of all earlier tiles and ``b`` the tile's block index.  Returns
+    (int32[1, bp] flat indices, 0 where a rank lies in another tile; the
+    tile's member count).  Hierarchical select via one-hot matmuls: row
+    first (inclusive row cumsum), then lane within the chosen row.
+    """
+    bp = rank.shape[0]
+    f32 = jnp.float32
+    rowsum = jnp.sum(sel.astype(jnp.int32), axis=1)  # (block_rows,)
+    blk_cnt = jnp.sum(rowsum)
+    # inclusive row cumsum via triangular mask-sum (exact: counts < 2^24)
+    r_i = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 0)
+    r_j = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 1)
+    tri_rows = (r_i <= r_j).astype(f32)  # [i, j] = i <= j
+    row_ck = _gather_dot(rowsum.astype(f32)[None, :], tri_rows)[0]
+
+    lr = rank - base                                 # local rank in tile
+    hit = (lr >= 0) & (lr < blk_cnt)
+    lr_f = jnp.clip(lr, 0, jnp.maximum(blk_cnt - 1, 0)).astype(f32)
+    # row r holds local member lr iff exclusive_ck[r] <= lr < inclusive
+    t_row = jnp.sum((row_ck[None, :] <= lr_f[:, None]).astype(f32), axis=1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bp, block_rows), 1)
+    onehot = (rows.astype(f32) == t_row[:, None]).astype(f32)
+    excl = row_ck - rowsum.astype(f32)               # exclusive cumsum
+    row_base = _gather_dot(onehot, excl[:, None])[:, 0]
+    selrow = _gather_dot(onehot, sel.astype(f32))    # (bp, LANES)
+    rem = lr_f - row_base
+    l_i = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    l_j = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    lane_ck = _gather_dot(selrow, (l_i <= l_j).astype(f32))  # inclusive
+    t_lane = jnp.sum((lane_ck <= rem[:, None]).astype(f32), axis=1)
+    flat = ((b * block_rows + t_row.astype(jnp.int32)) * LANES
+            + t_lane.astype(jnp.int32))
+    return jnp.where(hit, flat, 0)[None, :], blk_cnt
+
+
 def amper_sample_kernel(lo_ref, hi_ref, shift_ref, key_ref,
                         p_ref, valid_ref, idx_ref, stats_ref,
                         acc_ref, draw_ref,
-                        *, m: int, batch: int, csp_capacity: int,
-                        block_rows: int, n_real: int):
+                        *, m: int, csp_capacity: int, block_rows: int):
     """Grid (2, nblk), executed sequentially (TPU grid order).
 
     acc_ref (SMEM int32[4]): [total members, members below shift, live
@@ -117,10 +165,6 @@ def amper_sample_kernel(lo_ref, hi_ref, shift_ref, key_ref,
     nblk = pl.num_programs(1)
     bp = draw_ref.shape[1]
 
-    rows2d = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 0)
-    lanes2d = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 1)
-    gidx = (b * block_rows + rows2d) * LANES + lanes2d  # global flat index
-
     @pl.when((phase == 0) & (b == 0))
     def _init():
         acc_ref[0] = 0
@@ -130,6 +174,9 @@ def amper_sample_kernel(lo_ref, hi_ref, shift_ref, key_ref,
 
     @pl.when(phase == 0)
     def _count():
+        rows2d = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 0)
+        lanes2d = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 1)
+        gidx = (b * block_rows + rows2d) * LANES + lanes2d  # global flat index
         sel = _match_tile(p_ref[...], valid_ref[...], lo_ref, hi_ref, m)
         shift = shift_ref[0]
         acc_ref[0] += jnp.sum(sel.astype(jnp.int32))
@@ -142,26 +189,19 @@ def amper_sample_kernel(lo_ref, hi_ref, shift_ref, key_ref,
         s_shift = acc_ref[1]
         live = acc_ref[2]
         count = jnp.minimum(total, csp_capacity)
-        j = jax.lax.broadcasted_iota(jnp.uint32, (1, bp), 1)
-        nb = jnp.int32(batch)
-        # In-kernel jax.random.split(key): under the original threefry
-        # impl, split(key, 2).key_data == bits(key, (4,)) paired up, so
-        # the pick / fallback subkeys are four more cipher evaluations —
-        # the host never touches raw key data.
-        four = jnp.uint32(4)
-        pk = (counter_bits(key_ref, jnp.uint32(0), four),
-              counter_bits(key_ref, jnp.uint32(1), four))
-        fk = (counter_bits(key_ref, jnp.uint32(2), four),
-              counter_bits(key_ref, jnp.uint32(3), four))
-        pick = counter_bits(pk, j, nb)
-        fb = counter_bits(fk, j, nb)
+        j = jax.lax.broadcasted_iota(jnp.int32, (1, bp), 1)
+        # In-kernel jax.random.split(key): the pick / fallback subkeys are
+        # two more cipher evaluations, so the host never touches raw key
+        # data beyond the caller's one key.
+        pick = counter_bits(split_key(key_ref, 0), j)
+        fb = counter_bits(split_key(key_ref, 1), j)
         # same arithmetic as amper.pick_uniform: bits mod max(bound, 1)
         u = (pick % jnp.maximum(count, 1).astype(jnp.uint32)).astype(jnp.int32)
         rank = (u + s_shift) % jnp.maximum(total, 1)
         draw_ref[0:1, :] = rank
         draw_ref[1:2, :] = (fb % jnp.maximum(live, 1).astype(jnp.uint32)
                             ).astype(jnp.int32)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
+        idx_ref[...] = jnp.zeros((1, bp), jnp.int32)
         stats_ref[0] = total
         stats_ref[1] = s_shift
         stats_ref[2] = live
@@ -170,60 +210,28 @@ def amper_sample_kernel(lo_ref, hi_ref, shift_ref, key_ref,
     @pl.when(phase == 1)
     def _select():
         sel = _match_tile(p_ref[...], valid_ref[...], lo_ref, hi_ref, m)
-        sel_f = sel.astype(jnp.float32)
         base = acc_ref[3]
-        rowsum = jnp.sum(sel.astype(jnp.int32), axis=1)  # (block_rows,)
-        blk_cnt = jnp.sum(rowsum)
-        # inclusive row cumsum via triangular mask-sum (exact: counts < 2^24)
-        r_i = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 0)
-        r_j = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 1)
-        tri_rows = (r_i <= r_j).astype(jnp.float32)  # [i, j] = i <= j
-        row_ck = jnp.dot(rowsum.astype(jnp.float32)[None, :], tri_rows,
-                         preferred_element_type=jnp.float32)[0]  # inclusive
-
-        rank = draw_ref[0:1, :][0]                       # (bp,)
-        lr = rank - base                                 # local rank in block
-        hit = (lr >= 0) & (lr < blk_cnt)
-        lr_f = jnp.clip(lr, 0, jnp.maximum(blk_cnt - 1, 0)).astype(jnp.float32)
-        # row r holds local member lr iff exclusive_ck[r] <= lr < inclusive
-        below = (row_ck[None, :] <= lr_f[:, None]).astype(jnp.float32)
-        t_row = jnp.sum(below, axis=1)                   # (bp,) f32 row id
-        onehot = (jax.lax.broadcasted_iota(jnp.float32, (bp, block_rows), 1)
-                  == t_row[:, None]).astype(jnp.float32)
-        excl = row_ck - rowsum.astype(jnp.float32)       # exclusive cumsum
-        row_base = jnp.dot(onehot, excl[:, None],
-                           preferred_element_type=jnp.float32)[:, 0]
-        selrow = jnp.dot(onehot, sel_f,
-                         preferred_element_type=jnp.float32)  # (bp, LANES)
-        rem = lr_f - row_base
-        l_i = jax.lax.broadcasted_iota(jnp.float32, (LANES, LANES), 0)
-        l_j = jax.lax.broadcasted_iota(jnp.float32, (LANES, LANES), 1)
-        tri_lanes = (l_i <= l_j).astype(jnp.float32)
-        lane_ck = jnp.dot(selrow, tri_lanes,
-                          preferred_element_type=jnp.float32)  # inclusive
-        t_lane = jnp.sum((lane_ck <= rem[:, None]).astype(jnp.float32), axis=1)
-        flat = ((b * block_rows) + t_row) * LANES + t_lane
-        idx_ref[...] += jnp.where(hit[None, :], flat[None, :].astype(jnp.int32),
-                                  0)
+        found, blk_cnt = _rank_select_tile(sel, draw_ref[0:1, :][0], base, b,
+                                           block_rows)
+        idx_ref[...] += found
         acc_ref[3] = base + blk_cnt
 
     @pl.when((phase == 1) & (b == nblk - 1))
     def _finish():
-        total = acc_ref[0]
-        fb = draw_ref[1:2, :]
-        idx_ref[...] = jnp.where(total > 0, idx_ref[...], fb)
+        idx_ref[...] = jnp.where(acc_ref[0] > 0, idx_ref[...],
+                                 draw_ref[1:2, :])
 
 
 def amper_sample(pq: jax.Array, valid: jax.Array, lo: jax.Array,
                  hi: jax.Array, shift: jax.Array, key_data: jax.Array,
                  *, batch: int, csp_capacity: int,
-                 n_real: int, block_rows: int = DEFAULT_BLOCK_ROWS,
+                 block_rows: int = DEFAULT_BLOCK_ROWS,
                  interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """One fused dispatch: m-range match + CSP count + draw + rank gather.
 
     Args:
       pq: int32[R, 128] quantized priority table (R multiple of block_rows;
-        padding rows carry -1 / invalid).
+        padding rows carry -1 / invalid, so they never match).
       valid: bool[R, 128].
       lo, hi: int32[m] inclusive range bounds per group.
       shift: int32 scalar — the compaction rotation (from the roll key).
@@ -232,8 +240,6 @@ def amper_sample(pq: jax.Array, valid: jax.Array, lo: jax.Array,
         with ``jax.random.split``).
       batch: draws per call (static).
       csp_capacity: CSP buffer capacity (static; truncates the count).
-      n_real: flat length of the unpadded table (static; only documents
-        that real rows precede padding — padding never matches).
 
     Returns:
       (idx int32[batch] flat indices, stats int32[4] = [members, members
@@ -244,21 +250,17 @@ def amper_sample(pq: jax.Array, valid: jax.Array, lo: jax.Array,
     nblk = rows // block_rows
     bp = -(-batch // LANES) * LANES  # batch padded to the lane width
     idx, stats = pl.pallas_call(
-        functools.partial(amper_sample_kernel, m=m, batch=batch,
-                          csp_capacity=csp_capacity, block_rows=block_rows,
-                          n_real=n_real),
+        functools.partial(amper_sample_kernel, m=m,
+                          csp_capacity=csp_capacity, block_rows=block_rows),
         grid=(2, nblk),
         in_specs=[
-            pl.BlockSpec((m,), lambda p, b: (0,)),
-            pl.BlockSpec((m,), lambda p, b: (0,)),
-            pl.BlockSpec((1,), lambda p, b: (0,)),
-            pl.BlockSpec((2,), lambda p, b: (0,)),
+            smem_spec(), smem_spec(), smem_spec(), smem_spec(),
             pl.BlockSpec((block_rows, LANES), lambda p, b: (b, 0)),
             pl.BlockSpec((block_rows, LANES), lambda p, b: (b, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bp), lambda p, b: (0, 0)),
-            pl.BlockSpec((4,), lambda p, b: (0,)),
+            smem_spec(),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, bp), jnp.int32),
@@ -284,44 +286,17 @@ def rank_select_kernel(rank_ref, p_ref, valid_ref, lo_ref, hi_ref,
     """
     b = pl.program_id(0)
     nblk = pl.num_programs(0)
-    bp = rank_ref.shape[1]
 
     @pl.when(b == 0)
     def _init():
         acc_ref[0] = 0
-        idx_ref[...] = jnp.zeros_like(idx_ref)
+        idx_ref[...] = jnp.zeros(idx_ref.shape, jnp.int32)
 
     sel = _match_tile(p_ref[...], valid_ref[...], lo_ref, hi_ref, m)
-    sel_f = sel.astype(jnp.float32)
     base = acc_ref[0]
-    rowsum = jnp.sum(sel.astype(jnp.int32), axis=1)
-    blk_cnt = jnp.sum(rowsum)
-    r_i = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 0)
-    r_j = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 1)
-    tri_rows = (r_i <= r_j).astype(jnp.float32)
-    row_ck = jnp.dot(rowsum.astype(jnp.float32)[None, :], tri_rows,
-                     preferred_element_type=jnp.float32)[0]
-
-    rank = rank_ref[0:1, :][0]
-    lr = rank - base
-    hit = (lr >= 0) & (lr < blk_cnt)
-    lr_f = jnp.clip(lr, 0, jnp.maximum(blk_cnt - 1, 0)).astype(jnp.float32)
-    below = (row_ck[None, :] <= lr_f[:, None]).astype(jnp.float32)
-    t_row = jnp.sum(below, axis=1)
-    onehot = (jax.lax.broadcasted_iota(jnp.float32, (bp, block_rows), 1)
-              == t_row[:, None]).astype(jnp.float32)
-    excl = row_ck - rowsum.astype(jnp.float32)
-    row_base = jnp.dot(onehot, excl[:, None],
-                       preferred_element_type=jnp.float32)[:, 0]
-    selrow = jnp.dot(onehot, sel_f, preferred_element_type=jnp.float32)
-    rem = lr_f - row_base
-    l_i = jax.lax.broadcasted_iota(jnp.float32, (LANES, LANES), 0)
-    l_j = jax.lax.broadcasted_iota(jnp.float32, (LANES, LANES), 1)
-    tri_lanes = (l_i <= l_j).astype(jnp.float32)
-    lane_ck = jnp.dot(selrow, tri_lanes, preferred_element_type=jnp.float32)
-    t_lane = jnp.sum((lane_ck <= rem[:, None]).astype(jnp.float32), axis=1)
-    flat = ((b * block_rows) + t_row) * LANES + t_lane
-    idx_ref[...] += jnp.where(hit[None, :], flat[None, :].astype(jnp.int32), 0)
+    found, blk_cnt = _rank_select_tile(sel, rank_ref[0:1, :][0], base, b,
+                                       block_rows)
+    idx_ref[...] += found
     acc_ref[0] = base + blk_cnt
 
     @pl.when(b == nblk - 1)
@@ -356,12 +331,11 @@ def rank_select(pq: jax.Array, valid: jax.Array, lo: jax.Array,
             pl.BlockSpec((1, bp), lambda b: (0, 0)),
             pl.BlockSpec((block_rows, LANES), lambda b: (b, 0)),
             pl.BlockSpec((block_rows, LANES), lambda b: (b, 0)),
-            pl.BlockSpec((m,), lambda b: (0,)),
-            pl.BlockSpec((m,), lambda b: (0,)),
+            smem_spec(), smem_spec(),
         ],
         out_specs=[
             pl.BlockSpec((1, bp), lambda b: (0, 0)),
-            pl.BlockSpec((1,), lambda b: (0,)),
+            smem_spec(),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, bp), jnp.int32),

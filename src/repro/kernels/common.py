@@ -13,6 +13,8 @@ import contextlib
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 64  # (64, 128) int32 tile = 32 KiB VMEM per operand
@@ -54,6 +56,13 @@ def force_interpret(value: bool | None):
         yield
     finally:
         _INTERPRET_OVERRIDE = prev
+
+
+def smem_spec() -> pl.BlockSpec:
+    """A whole small array (range bounds, scalars, counters) resident in
+    SMEM for every grid step: Mosaic stores and reads scalars there, and
+    refuses scalar stores to VMEM."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def auto_block_rows(n: int) -> int:

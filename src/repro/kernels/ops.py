@@ -105,12 +105,12 @@ def amper_sample(pq: jax.Array, valid: jax.Array, lo: jax.Array,
       live rows, truncated CSP count]).
     """
     block_rows = _auto_block_rows(pq.shape[0]) if block_rows is None else block_rows
-    pq2, valid2, n = _pad_table(pq, valid, block_rows)
+    pq2, valid2, _n = _pad_table(pq, valid, block_rows)
     idx, stats = _as.amper_sample(
         pq2, valid2, lo.astype(jnp.int32), hi.astype(jnp.int32),
         jnp.asarray(shift, jnp.int32),
         jax.random.key_data(key).astype(jnp.uint32),
-        batch=batch, csp_capacity=csp_capacity, n_real=n,
+        batch=batch, csp_capacity=csp_capacity,
         block_rows=block_rows, interpret=interpret)
     return idx, stats
 

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import DEFAULT_BLOCK_ROWS, LANES
+from repro.kernels.common import DEFAULT_BLOCK_ROWS, LANES, smem_spec
 
 
 def tcam_match_kernel(q_ref, mask_ref, p_ref, out_ref):
@@ -60,8 +60,7 @@ def tcam_match(pq: jax.Array, query: jax.Array, mask: jax.Array,
         tcam_match_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            smem_spec(), smem_spec(),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
@@ -73,21 +72,25 @@ def tcam_match(pq: jax.Array, query: jax.Array, mask: jax.Array,
 def multi_query_kernel(lo_ref, hi_ref, p_ref, valid_ref, sel_ref, cnt_ref, *, m: int):
     """Fused m-range match on one tile: OR'd selection + per-group counts.
 
-    cnt_ref is (1, m) per grid step; the caller sums over grid steps.  The
-    in-kernel loop over m is unrolled (m is small, <= 32) so each tile is
-    read from VMEM once and compared m times — the VPU analogue of issuing
-    m TCAM searches while the array is precharged.
+    cnt_ref is the whole int32[m] count vector in SMEM, resident across
+    the (sequential) grid and accumulated per tile.  The in-kernel loop
+    over m is unrolled (m is small, <= 32) so each tile is read from VMEM
+    once and compared m times — the VPU analogue of issuing m TCAM
+    searches while the array is precharged.
     """
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        for i in range(m):
+            cnt_ref[i] = 0
+
     p = p_ref[...]
     valid = valid_ref[...]
     sel = jnp.zeros(p.shape, jnp.bool_)
-    counts = jnp.zeros((m,), jnp.int32)
     for i in range(m):
         match = (p >= lo_ref[i]) & (p <= hi_ref[i]) & valid
         sel = sel | match
-        counts = counts.at[i].set(jnp.sum(match.astype(jnp.int32)))
+        cnt_ref[i] += jnp.sum(match.astype(jnp.int32))
     sel_ref[...] = sel
-    cnt_ref[0, :] = counts
 
 
 def multi_query_match(pq: jax.Array, valid: jax.Array, lo: jax.Array,
@@ -99,24 +102,21 @@ def multi_query_match(pq: jax.Array, valid: jax.Array, lo: jax.Array,
     """
     rows = pq.shape[0]
     m = lo.shape[0]
-    nblk = rows // block_rows
-    sel, cnt = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(multi_query_kernel, m=m),
-        grid=(nblk,),
+        grid=(rows // block_rows,),
         in_specs=[
-            pl.BlockSpec((m,), lambda i: (0,)),
-            pl.BlockSpec((m,), lambda i: (0,)),
+            smem_spec(), smem_spec(),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
+            smem_spec(),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.bool_),
-            jax.ShapeDtypeStruct((nblk, m), jnp.int32),
+            jax.ShapeDtypeStruct((m,), jnp.int32),
         ],
         interpret=interpret,
     )(lo, hi, pq, valid)
-    return sel, jnp.sum(cnt, axis=0)

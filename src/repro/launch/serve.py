@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_api import Model
 
 
@@ -30,6 +31,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_reduced_config(args.arch) if args.reduced
            else get_config(args.arch))

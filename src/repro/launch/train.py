@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced_config
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models.model_api import Model
 from repro.train import checkpoint as ckpt_mod
@@ -59,6 +60,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_reduced_config(args.arch) if args.reduced
            else get_config(args.arch))

@@ -16,7 +16,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import axis_size, logical_constraint
+from repro.distributed.sharding import logical_constraint
 from repro.models.common import ParamSpec
 from repro.models import mlp as mlp_mod
 
@@ -60,7 +60,6 @@ def moe_apply_shard_map(cfg, p: dict, x: jax.Array) -> Tuple[jax.Array, dict]:
     ~3 GB/layer of all-gather replaces ~60 GB/layer of all-reduce.
     Activated via cfg.moe_dispatch == "shard_map" when a mesh is active.
     """
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.sharding import active_rules
     from jax.sharding import PartitionSpec as P
 
@@ -111,7 +110,7 @@ def moe_apply_shard_map(cfg, p: dict, x: jax.Array) -> Tuple[jax.Array, dict]:
         # my token group's slice from every expert owner: (E, Cl, D)
         g_lin = jnp.int32(0)
         for a in dp_axes:
-            g_lin = g_lin * axis_size(a) + jax.lax.axis_index(a)
+            g_lin = g_lin * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         my_slice = jax.lax.dynamic_slice_in_dim(
             eo.reshape(E_loc, G, Cl, D).transpose(1, 0, 2, 3),  # (G,E_loc,Cl,D)
             g_lin, 1, 0)[0]                                     # (E_loc, Cl, D)
@@ -132,13 +131,13 @@ def moe_apply_shard_map(cfg, p: dict, x: jax.Array) -> Tuple[jax.Array, dict]:
         return out, stats
 
     tok_spec = P(dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes else None))
-    out, stats = shard_map(
+    out, stats = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(tok_spec[0], None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(tok_spec[0], None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x.reshape(T, D), p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     out = out.reshape(B, S, D)

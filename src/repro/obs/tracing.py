@@ -13,7 +13,7 @@ registry.  Three properties make it safe to leave in library code:
   wall time is *compile* time, not run time — recording it would poison
   the histograms with one bogus multi-second sample per compile — and
   host callbacks have no place on the hot path.  Spans therefore no-op
-  whenever ``jax.core.trace_state_clean()`` is False.  Instrumentation
+  whenever ``jax.core.trace_ctx.is_top_level()`` is False.  Instrumentation
   is host-side only either way, so it can never add an XLA dispatch to
   a jitted program (pinned by the tier-1 guard in tests/test_obs.py).
 * **profiler-integrated.**  With ``profile=True`` on the registry's
@@ -110,12 +110,10 @@ class _Span:
 
 
 def _trace_state_clean() -> bool:
-    try:
-        import jax.core
+    """True outside every jax trace (jit, grad, vmap, shard_map)."""
+    import jax.core
 
-        return jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - ancient/future jax
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 def span(name: str, registry: Registry | None = None):
@@ -132,10 +130,7 @@ def span(name: str, registry: Registry | None = None):
                          bounds=TIME_BUCKETS_MS)
     annotation = None
     if _profile:
-        try:
-            import jax.profiler
+        import jax.profiler
 
-            annotation = jax.profiler.TraceAnnotation(name)
-        except Exception:  # pragma: no cover
-            annotation = None
+        annotation = jax.profiler.TraceAnnotation(name)
     return _Span(hist, annotation)

@@ -748,6 +748,8 @@ class ReplayService:
                      else float(self.dqn.beta_at(
                          max(learner.steps_done - 1, 0)))),
             "feedback_seqs": rec["feedback_seqs"],
+            # Feedback slabs (slab x batch priority rows each) applied.
+            "feedback_applied": rec["fb_applied"],
             # Compatibility view over the registry's staleness histogram:
             # count/sum are exact, max is exact, and the INT_BUCKETS
             # bounds make the percentiles exact for staleness <= 64.

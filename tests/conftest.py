@@ -48,6 +48,8 @@ def mesh():
     """2x4 ("pod", "data") mesh over the 8 forced host devices."""
     import jax
 
+    from repro.launch.mesh import make_mesh
+
     if jax.device_count() < 8:
         pytest.skip("needs 8 devices (XLA_FLAGS was set before jax init?)")
-    return jax.make_mesh((2, 4), ("pod", "data"))
+    return make_mesh((2, 4), ("pod", "data"))

@@ -138,7 +138,7 @@ def test_dtype_promotion_clean_on_registry_samplers():
 def test_dtype_scan_flags_wide_and_weak():
     from repro.analysis.jaxpr_lint import _weak_outputs, scan_jaxpr_dtypes
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: jnp.cumsum(x * 2.0))(jnp.arange(4, dtype=jnp.float64))
     wide = scan_jaxpr_dtypes(closed.jaxpr, "x64-fixture")

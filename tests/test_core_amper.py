@@ -148,3 +148,17 @@ def test_update_is_plain_write(table):
     st = s.update(st, jnp.array([5]), jnp.array([0.123]))
     got = float(s.priorities(st)[5])
     assert abs(got - 0.123) < 1e-5
+
+
+@pytest.mark.parametrize("field", ["fr_mode", "knn_mode"])
+def test_unknown_search_mode_raises(field):
+    """A misspelled mode must fail loudly, not fall through to another
+    search path — both through the registry and the free function."""
+    from repro.core.samplers import make_sampler
+
+    with pytest.raises(ValueError, match=f"unknown {field}"):
+        make_sampler("amper-fr", 256, **{field: "fussed"})
+    cfg = AmperConfig(capacity=256)._replace(**{field: "fussed"})
+    with pytest.raises(ValueError, match=f"unknown {field}"):
+        build_csp_fr(jnp.zeros(256, jnp.int32), jnp.ones(256, bool),
+                     jax.random.key(0), cfg)

@@ -2,7 +2,7 @@
 
 ``tests/golden/envs.json`` holds checked-in obs/reward/done sequences
 for every registered env at fixed seeds and a fixed action pattern
-(generated once from the transcribed-from-gym dynamics).  Any refactor
+(generated from the transcribed-from-gym dynamics).  Any refactor
 of the physics — integrator, constants, termination, auto-reset — that
 drifts a trajectory fails here instead of silently shifting learning
 curves three benchmarks downstream.
@@ -12,6 +12,12 @@ return), i.e. the values the TD target consumes, so auto-reset behavior
 is pinned too (via the ``done`` flags).  Fixtures predating the
 terminated/truncated split carry no ``terminated`` stream; newer ones
 (the pixel envs) pin it as well.
+
+Regenerate (only when the PRNG stream itself changes, never to absorb a
+physics change) with ``PYTHONPATH=src python tests/test_env_golden.py``:
+it replays each fixture's own action pattern and rewrites every stream.
+Before regenerating, check that the old fixture still passes under the
+old stream, which shows the dynamics did not move.
 """
 import json
 import os
@@ -156,3 +162,31 @@ def test_freeway_scores_at_top_and_never_terminates():
     s2, obs, r, d, term = env.step(s, jnp.int32(1), jax.random.key(1))
     assert float(r) == 1.0 and not bool(term)
     assert float(s2.x[0]) == 9.0          # crossing restarts at the bottom
+
+
+def _regenerate(path: str = GOLDEN) -> None:
+    """Rewrite the fixture under the current PRNG stream (same actions)."""
+    out = {}
+    for name, fx in _FIXTURES.items():
+        env = envs_mod.make_env(name)
+        state = env.reset(jax.random.key(0))
+        rec = {"reset_obs": np.asarray(env.obs(state)).tolist(),
+               "actions": fx["actions"], "obs": [], "reward": [],
+               "done": []}
+        if "terminated" in fx:
+            rec["terminated"] = []
+        for t, a in enumerate(fx["actions"]):
+            state, obs, r, d, term = env.step(
+                state, jnp.int32(a), jax.random.fold_in(jax.random.key(1), t))
+            rec["obs"].append(np.asarray(obs).tolist())
+            rec["reward"].append(float(r))
+            rec["done"].append(bool(d))
+            if "terminated" in rec:
+                rec["terminated"].append(bool(term))
+        out[name] = rec
+    with open(path, "w") as f:
+        json.dump(out, f)
+
+
+if __name__ == "__main__":
+    _regenerate()

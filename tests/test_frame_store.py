@@ -19,8 +19,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.amper import FR_MODES
 from repro.core.replay_buffer import FrameStore, ReplayBuffer
 from repro.core.samplers import make_sampler
+from repro.launch.mesh import make_mesh
 from repro.rl.dqn import DQNConfig, make_dqn
 from repro.runtime import ReplayService
 from repro.train import replay_checkpoint as rck
@@ -219,7 +221,7 @@ def test_uint8_elastic_restore_onto_fewer_shards(tmp_path, to_shards):
         pytest.skip("needs 8 devices")
 
     def sharded(n):
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
         return _pixel_rb(make_sampler("amper-fr-sharded", 256, mesh=mesh,
                                       axis_names=("data",), v_max=8.0))
 
@@ -270,7 +272,7 @@ def test_pixel_fr_modes_bit_identical_dense():
     cap = 512
     hist = _gen_stream(17, 1, 600)
     out = {}
-    for mode in ("broadcast", "interval", "window", "kernel", "fused"):
+    for mode in FR_MODES:
         rb = _pixel_rb_cap(cap, make_sampler("amper-fr", cap, v_max=8.0,
                                              fr_mode=mode))
         st = _fill(rb, hist, 1)
@@ -294,7 +296,7 @@ def test_pixel_fused_bit_identical_per_mesh(n_shards):
     out = {}
     for mode in ("broadcast", "fused"):
         s = make_sampler("amper-fr-sharded", cap, v_max=8.0, fr_mode=mode,
-                         mesh=jax.make_mesh((n_shards,), ("data",)))
+                         mesh=make_mesh((n_shards,), ("data",)))
         rb = _pixel_rb_cap(cap, s)
         st = _fill(rb, hist, 1)
         idx, batch, w = rb.sample(st, jax.random.key(23), 64)

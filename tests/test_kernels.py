@@ -94,26 +94,30 @@ def test_kernel_amper_parity_large():
 @pytest.mark.parametrize("seed", [0, 1, 7])
 def test_counter_bits_matches_jax_random_bits(n, seed):
     """The kernel's per-lane threefry recomputation is bit-exact with
-    jax.random.bits at every size, including odd (trailing-0 padding)."""
+    jax.random.bits at every size, odd sizes included, and needs no n."""
     from repro.kernels.amper_sample import counter_bits
     key = jax.random.key(seed)
     kd = jax.random.key_data(key).astype(jnp.uint32)
     expect = jax.random.bits(key, (n,), jnp.uint32)
-    got = counter_bits(kd, jnp.arange(n, dtype=jnp.uint32), jnp.uint32(n))
+    got = counter_bits(kd, jnp.arange(n, dtype=jnp.uint32))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
 
 
 def test_counter_bits_split_identity():
-    """split(key, 2).key_data == bits(key, (4,)) paired up — the identity
-    the kernel uses to derive its pick/fallback subkeys in-kernel."""
-    from repro.kernels.amper_sample import counter_bits
+    """split(key)[i].key_data == threefry2x32(key, (0, i)) — the identity
+    the kernel uses to derive its pick/fallback subkeys in-kernel — and
+    the subkeys' draws match jax.random.bits."""
+    from repro.kernels.amper_sample import counter_bits, split_key
     key = jax.random.key(11)
     kd = jax.random.key_data(key).astype(jnp.uint32)
     ks = jax.random.split(key)
-    got = counter_bits(kd, jnp.arange(4, dtype=jnp.uint32), jnp.uint32(4))
+    got = np.asarray([split_key(kd, i) for i in range(2)], np.uint32)
     np.testing.assert_array_equal(
-        np.asarray(got).reshape(2, 2),
-        np.asarray(jax.random.key_data(ks)).astype(np.uint32))
+        got, np.asarray(jax.random.key_data(ks)).astype(np.uint32))
+    j = jnp.arange(33, dtype=jnp.uint32)
+    np.testing.assert_array_equal(
+        np.asarray(counter_bits(split_key(kd, 1), j)),
+        np.asarray(jax.random.bits(ks[1], (33,), jnp.uint32)))
 
 
 # --- fused amper_sample: whole-draw bit-identity + edge cases -----------------

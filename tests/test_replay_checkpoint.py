@@ -11,6 +11,7 @@ import pytest
 from repro.core.replay_buffer import ReplayBuffer
 from repro.core import sharded as sharded_mod
 from repro.core.samplers import abstract_state, make_sampler
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as ck
 from repro.train import replay_checkpoint as rck
 
@@ -233,7 +234,7 @@ def test_nstep_restore_into_wrong_horizon_raises(tmp_path):
 
 
 def _sharded_rb(n_shards):
-    mesh = jax.make_mesh((n_shards,), ("data",))
+    mesh = make_mesh((n_shards,), ("data",))
     s = make_sampler("amper-fr-sharded", CAP, mesh=mesh,
                      axis_names=("data",), v_max=8.0)
     return ReplayBuffer(CAP, s)

@@ -23,20 +23,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
 
 import repro.core.quantize as qz
-from repro.core.amper import AmperConfig, build_csp_fr
+from repro.core.amper import FR_MODES, AmperConfig, build_csp_fr
 from repro.core.replay_buffer import ReplayBuffer
 from repro.core.samplers import Sampler, available_samplers, make_sampler
-
-FR_MODES = ("broadcast", "interval", "window", "kernel", "fused")
+from repro.launch.mesh import make_replay_mesh
 
 
 def _mesh_of(n_shards):
     if jax.device_count() < n_shards:
         pytest.skip(f"needs {n_shards} devices")
-    return Mesh(np.asarray(jax.devices()[:n_shards]), ("data",))
+    return make_replay_mesh(n_shards)
 
 
 def _random_table(seed, n, v_max=1.0, saturate=True, invalidate=True):
