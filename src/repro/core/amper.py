@@ -445,12 +445,8 @@ class AmperSampler:
         return AmperState(pq=pq, valid=valid)
 
     def build_csp(self, state: AmperState, key: jax.Array) -> CspResult:
-        from repro.obs import span  # deferred: keep core import-light
-
         fn = build_csp_fr if self.variant == "fr" else build_csp_k
-        # No-op under jit (the usual path); times the eager CSP rebuild
-        # in tests/benchmarks/probes.
-        with span("csp_rebuild"):
+        with jax.named_scope("csp_build"):
             return fn(state.pq, state.valid, key, self.cfg)
 
     def sample(self, state: AmperState, key: jax.Array, batch: int,
@@ -460,8 +456,9 @@ class AmperSampler:
         if self.variant == "fr" and self.cfg.fr_mode == "fused":
             return self._sample_fused(state, kcsp, kpick, batch)
         csp = self.build_csp(state, kcsp)
-        live = jnp.sum(state.valid.astype(jnp.int32))
-        return sample_from_csp(csp, kpick, batch, live)
+        with jax.named_scope("csp_pick"):
+            live = jnp.sum(state.valid.astype(jnp.int32))
+            return sample_from_csp(csp, kpick, batch, live)
 
     def _sample_fused(self, state: AmperState, kcsp: jax.Array,
                       kpick: jax.Array, batch: int) -> jax.Array:

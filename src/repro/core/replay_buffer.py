@@ -419,13 +419,15 @@ class ReplayBuffer:
         of the single flattened write.
         """
         t, b = jax.tree.leaves(block)[0].shape[:2]
-        if self.accumulator is not None and not aggregated:
-            state, _ = jax.lax.scan(
-                lambda s, tr: (self.add_batch(s, tr), None), state, block)
-            return state
-        flat = jax.tree.map(
-            lambda x: x.reshape((t * b,) + x.shape[2:]), block)
-        return self._write_arc(state, flat)
+        with jax.named_scope("ring_write"):
+            if self.accumulator is not None and not aggregated:
+                state, _ = jax.lax.scan(
+                    lambda s, tr: (self.add_batch(s, tr), None), state,
+                    block)
+                return state
+            flat = jax.tree.map(
+                lambda x: x.reshape((t * b,) + x.shape[2:]), block)
+            return self._write_arc(state, flat)
 
     def _stack_frames(self, state: ReplayState, slot0: jax.Array,
                       ref: jax.Array, base_ok: jax.Array) -> jax.Array:
@@ -515,21 +517,19 @@ class ReplayBuffer:
         (see :meth:`materialize`); the stored uint8 frames never leave
         the buffer.
         """
-        from repro.obs import span  # deferred: keep core import-light
-
-        # No-op under jit; times eager draws (tests/benchmarks/probes).
-        with span("replay_sample"):
-            idx = self.sampler.sample(state.sampler_state, key, batch)
+        idx = self.sampler.sample(state.sampler_state, key, batch)
         if self.frame_store is not None:
-            batch_tree = self.materialize(state, idx)
+            with jax.named_scope("frame_stack"):
+                batch_tree = self.materialize(state, idx)
         else:
             batch_tree = jax.tree.map(lambda buf: buf[idx], state.storage)
-        prios = self.sampler.priorities(state.sampler_state)
-        # Shared weight formula (one normalisation constant for the
-        # reference and fused paths — see per.importance_from_selected).
-        w = importance_from_selected(prios[idx], jnp.sum(prios),
-                                     jnp.maximum(state.size, 1),
-                                     self.beta if beta is None else beta)
+        with jax.named_scope("is_weights"):
+            prios = self.sampler.priorities(state.sampler_state)
+            # Shared weight formula (one normalisation constant for the
+            # reference and fused paths — see per.importance_from_selected).
+            w = importance_from_selected(prios[idx], jnp.sum(prios),
+                                         jnp.maximum(state.size, 1),
+                                         self.beta if beta is None else beta)
         return idx, batch_tree, w
 
     def stamps(self, state: ReplayState, idx: jax.Array) -> jax.Array:
@@ -553,16 +553,18 @@ class ReplayBuffer:
         counter (a slot recycled exactly 2^32 adds later repeats its
         stamp but not its generation).
         """
-        p = (jnp.abs(td_error) + self.eps) ** self.alpha
-        if stamp is None:
-            sampler_state = self.sampler.update(state.sampler_state, idx, p)
-            p_max = jnp.max(p)
-        else:
-            valid = ((state.write_stamp[idx] == stamp[..., 0])
-                     & (state.write_gen[idx] == stamp[..., 1]))
-            sampler_state = masked_update(
-                self.sampler, state.sampler_state, idx, p, valid)
-            p_max = jnp.max(jnp.where(valid, p, 0.0))
+        with jax.named_scope("priority_write"):
+            p = (jnp.abs(td_error) + self.eps) ** self.alpha
+            if stamp is None:
+                sampler_state = self.sampler.update(
+                    state.sampler_state, idx, p)
+                p_max = jnp.max(p)
+            else:
+                valid = ((state.write_stamp[idx] == stamp[..., 0])
+                         & (state.write_gen[idx] == stamp[..., 1]))
+                sampler_state = masked_update(
+                    self.sampler, state.sampler_state, idx, p, valid)
+                p_max = jnp.max(jnp.where(valid, p, 0.0))
         return state._replace(
             sampler_state=sampler_state,
             max_priority=jnp.maximum(state.max_priority, p_max),

@@ -132,53 +132,60 @@ def _fr_sample_body(cfg: AmperConfig, batch: int, axis_names: tuple[str, ...],
         n_local = pq_local.shape[0]
         kq, kpick = jax.random.split(key)
         kpick, kfb = jax.random.split(kpick)  # fallback gets its OWN key
-        v_rep = group_representatives(kq, cfg)  # identical on all shards
-        if cfg.fr_mode == "fused":
-            # Fused pick: the rank-select kernel turns each owned draw
-            # straight into its member index in one pass over the shard's
-            # slice — no compacted index buffer.  Membership (and hence
-            # counts, owners, offsets) reuses the multi-query kernel, so
-            # the whole draw is bit-identical to the reference modes:
-            # rank r in index order IS nonzero(selected)[r].
-            from repro.kernels import ops as kops
-            selected = _local_match_fr(pq_local, valid_local, v_rep, cfg)
-            loc_count = jnp.minimum(
-                jnp.sum(selected.astype(jnp.int32)), local_cap)
+        with jax.named_scope("csp_build"):
+            v_rep = group_representatives(kq, cfg)  # identical on all shards
+            if cfg.fr_mode == "fused":
+                # Fused pick: the rank-select kernel turns each owned
+                # draw straight into its member index in one pass over
+                # the shard's slice — no compacted index buffer.
+                # Membership (and hence counts, owners, offsets) reuses
+                # the multi-query kernel, so the whole draw is
+                # bit-identical to the reference modes: rank r in index
+                # order IS nonzero(selected)[r].
+                from repro.kernels import ops as kops
+                selected = _local_match_fr(pq_local, valid_local, v_rep, cfg)
+                loc_count = jnp.minimum(
+                    jnp.sum(selected.astype(jnp.int32)), local_cap)
 
-            def pick_local(offset):
-                lo, hi = fr_intervals(v_rep, cfg)
-                idx, _cnt = kops.rank_select(pq_local, valid_local, lo, hi,
-                                             offset)
-                return idx
-        else:
-            selected = _local_match_fr(pq_local, valid_local, v_rep, cfg)
-            (loc_idx,) = jnp.nonzero(selected, size=local_cap, fill_value=0)
-            loc_count = jnp.minimum(
-                jnp.sum(selected.astype(jnp.int32)), local_cap)
+                def pick_local(offset):
+                    lo, hi = fr_intervals(v_rep, cfg)
+                    idx, _cnt = kops.rank_select(pq_local, valid_local,
+                                                 lo, hi, offset)
+                    return idx
+            else:
+                selected = _local_match_fr(pq_local, valid_local, v_rep, cfg)
+                (loc_idx,) = jnp.nonzero(selected, size=local_cap,
+                                         fill_value=0)
+                loc_count = jnp.minimum(
+                    jnp.sum(selected.astype(jnp.int32)), local_cap)
 
-            def pick_local(offset):
-                return loc_idx[jnp.clip(offset, 0, local_cap - 1)]
+                def pick_local(offset):
+                    return loc_idx[jnp.clip(offset, 0, local_cap - 1)]
 
-        counts = jax.lax.all_gather(loc_count, axis_names, tiled=False)
-        counts = counts.reshape(-1)  # (n_shards,)
-        cum = jnp.cumsum(counts)
-        total = cum[-1]
+        with jax.named_scope("csp_pick"):
+            counts = jax.lax.all_gather(loc_count, axis_names, tiled=False)
+            counts = counts.reshape(-1)  # (n_shards,)
+            cum = jnp.cumsum(counts)
+            total = cum[-1]
 
-        # Identical draws on every shard (same key): u in [0, total).
-        u = jax.random.randint(kpick, (batch,), 0, jnp.maximum(total, 1))
-        owner = jnp.searchsorted(cum, u, side="right").astype(jnp.int32)
-        start = cum - counts  # exclusive prefix
-        offset = u - start[jnp.clip(owner, 0, counts.shape[0] - 1)]
+            # Identical draws on every shard (same key): u in [0, total).
+            u = jax.random.randint(kpick, (batch,), 0,
+                                   jnp.maximum(total, 1))
+            owner = jnp.searchsorted(cum, u,
+                                     side="right").astype(jnp.int32)
+            start = cum - counts  # exclusive prefix
+            offset = u - start[jnp.clip(owner, 0, counts.shape[0] - 1)]
 
-        me = _flat_axis_index(axis_names)
-        mine = owner == me
-        local_pick = pick_local(offset).astype(jnp.int32)
-        contrib = jnp.where(mine, local_pick + me * n_local, 0)
-        picked = jax.lax.psum(contrib, axis_names)
+            me = _flat_axis_index(axis_names)
+            mine = owner == me
+            local_pick = pick_local(offset).astype(jnp.int32)
+            contrib = jnp.where(mine, local_pick + me * n_local, 0)
+            picked = jax.lax.psum(contrib, axis_names)
 
-        # Fallback: empty CSP -> uniform over the global table.
-        fb = jax.random.randint(kfb, (batch,), 0, n_local * _n_shards(axis_names))
-        return jnp.where(total > 0, picked, fb).astype(jnp.int32)
+            # Fallback: empty CSP -> uniform over the global table.
+            fb = jax.random.randint(kfb, (batch,), 0,
+                                    n_local * _n_shards(axis_names))
+            return jnp.where(total > 0, picked, fb).astype(jnp.int32)
 
     return body
 
@@ -326,11 +333,7 @@ class ShardedAmperSampler(AmperSampler):
     def sample(self, state: AmperState, key: jax.Array, batch: int,
                stratified: bool = True) -> jax.Array:
         del stratified  # CSP sampling is uniform by construction
-        from repro.obs import span  # deferred: keep core import-light
-
-        # No-op under jit; times the eager sharded dispatch path.
-        with span("sharded_sample"):
-            return self._sample_fn(batch)(state.pq, state.valid, key)
+        return self._sample_fn(batch)(state.pq, state.valid, key)
 
     def membership(self, state: AmperState, key: jax.Array) -> jax.Array:
         """Global bool[capacity] CSP membership for ``key`` (test/analysis

@@ -1,17 +1,16 @@
 """Span-based wall-time tracing over the metrics registry.
 
-``span("csp_rebuild")`` wraps a host-side region and records its wall
-time into the histogram ``span_csp_rebuild_ms`` of the *current*
-registry.  Three properties make it safe to leave in library code:
+``span("learn")`` wraps a host-side region and records its wall time
+into the histogram ``span_learn_ms`` of the *current* registry.  Three
+properties make it safe to leave in library code:
 
 * **disabled is one branch.**  With the current registry disabled (the
   process default), entering a span resolves to a shared no-op object;
   nothing is allocated or timed.
-* **trace-safe.**  Library functions like ``ReplayBuffer.sample`` or
-  ``AmperSampler.build_csp`` run both eagerly (tests, notebooks,
-  benchmarks) and under ``jax.jit``.  Under a jit trace the region's
-  wall time is *compile* time, not run time — recording it would poison
-  the histograms with one bogus multi-second sample per compile — and
+* **trace-safe.**  Code that holds a span may run both eagerly and
+  under ``jax.jit``.  Under a jit trace the region's wall time is
+  *compile* time, not run time — recording it would poison the
+  histograms with one bogus multi-second sample per compile — and
   host callbacks have no place on the hot path.  Spans therefore no-op
   whenever ``jax.core.trace_ctx.is_top_level()`` is False.  Instrumentation
   is host-side only either way, so it can never add an XLA dispatch to
@@ -20,7 +19,21 @@ registry.  Three properties make it safe to leave in library code:
   telemetry config (or ``obs.configure(profile=True)``), spans also
   open a ``jax.profiler.TraceAnnotation`` so they show up as named
   regions in TensorBoard/perfetto traces next to the XLA ops they
-  bracket.
+  bracket, on the profiler's clock and on their own thread's line.
+  Keyword arguments (``span("learn", slab=seq0)``) become the
+  annotation's event stats; the histogram ignores them, and nothing is
+  built from them when profiling is off.
+
+Device regions inside a jitted program are named with
+``jax.named_scope`` instead (``csp_build``, ``frame_stack``,
+``td_loss`` ...): a span inside a trace is a no-op.
+
+Compiles are counted by one process-wide ``jax.monitoring`` listener,
+installed with the first enabled registry: every lowering of a jaxpr to
+an MLIR module (``/jax/core/compile/jaxpr_to_mlir_module_duration``,
+one per compile whether or not the persistent cache then serves it)
+adds one to the counter ``jit_compiles_total`` of the registry current
+on the compiling thread and its lowering time to ``jit_compile_ms``.
 """
 from __future__ import annotations
 
@@ -38,6 +51,11 @@ _state = threading.local()
 _global_registry: Registry = _default_registry
 _profile = False
 
+COMPILES = "jit_compiles_total"
+_LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_compile_listener_installed = False
+_install_lock = threading.Lock()
+
 
 def get_registry() -> Registry:
     """The active registry (thread-local override, then process global)."""
@@ -52,6 +70,8 @@ def set_registry(registry: Optional[Registry], profile: bool = False
     installed registry (None if it was the default) so callers can
     restore it when their run ends."""
     global _global_registry, _profile
+    if registry is not None and registry.enabled:
+        _install_compile_listener()
     prev = _global_registry
     _global_registry = registry if registry is not None else _default_registry
     _profile = profile
@@ -65,6 +85,8 @@ class use_registry:
         self._reg = reg
 
     def __enter__(self):
+        if self._reg.enabled:
+            _install_compile_listener()
         self._prev = getattr(_state, "registry", None)
         _state.registry = self._reg
         return self._reg
@@ -116,11 +138,12 @@ def _trace_state_clean() -> bool:
     return jax.core.trace_ctx.is_top_level()
 
 
-def span(name: str, registry: Registry | None = None):
+def span(name: str, registry: Registry | None = None, **args):
     """Wall-time span context manager -> histogram ``span_<name>_ms``.
 
     No-op (a shared null object) when the resolved registry is disabled
     or the caller is executing inside a jax trace (see module docstring).
+    ``args`` go to the profiler annotation only, when profiling is on.
     """
     reg = registry if registry is not None else get_registry()
     if not reg.enabled or not _trace_state_clean():
@@ -132,5 +155,39 @@ def span(name: str, registry: Registry | None = None):
     if _profile:
         import jax.profiler
 
-        annotation = jax.profiler.TraceAnnotation(name)
+        annotation = jax.profiler.TraceAnnotation(name, **args)
     return _Span(hist, annotation)
+
+
+def _on_duration_event(event: str, duration_secs: float, **_) -> None:
+    if event != _LOWERING_EVENT:
+        return
+    reg = get_registry()
+    if not reg.enabled:
+        return
+    _compiles(reg).add()
+    reg.histogram("jit_compile_ms", help="lowering wall time (ms)",
+                  bounds=TIME_BUCKETS_MS).observe(duration_secs * 1e3)
+
+
+def _install_compile_listener() -> None:
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    import jax.monitoring
+
+    with _install_lock:
+        if not _compile_listener_installed:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration_event)
+            _compile_listener_installed = True
+
+
+def _compiles(reg: Registry):
+    return reg.counter(COMPILES, help="jaxpr lowerings (one per compile)")
+
+
+def compile_count(registry: Registry | None = None) -> int:
+    """Compiles recorded so far in ``registry`` (default: the current)."""
+    reg = registry if registry is not None else get_registry()
+    return int(_compiles(reg).value)

@@ -336,9 +336,11 @@ def make_dqn(cfg: DQNConfig) -> DQN:
     def learn(params, target_params, opt_m, opt_v, step, batch, weights):
         """One TD gradient step on a sampled batch (the learner piece)."""
         w = weights if is_per else jnp.ones_like(weights)
-        (loss, td), grads = jax.value_and_grad(
-            td_loss, has_aux=True)(params, target_params, batch, w)
-        params, m, v = adam(params, grads, opt_m, opt_v, step)
+        with jax.named_scope("td_loss"):
+            (loss, td), grads = jax.value_and_grad(
+                td_loss, has_aux=True)(params, target_params, batch, w)
+        with jax.named_scope("adam"):
+            params, m, v = adam(params, grads, opt_m, opt_v, step)
         return params, m, v, td, loss
 
     def agent_step(state: AgentState, key) -> tuple[AgentState, dict]:

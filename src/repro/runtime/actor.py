@@ -232,13 +232,17 @@ class Actor(threading.Thread):
         self._publish_run_state(env_state, obs, ep_ret, nstep, step, chunk)
         while not self._stop_evt.is_set():
             if self._gate is not None:
-                self._gate.wait_if_paused(self._stop_evt)
+                with span("actor_wait"):
+                    self._gate.wait_if_paused(self._stop_evt)
             # Replay-ratio throttle: don't burn host cores producing frames
             # the learner can't consume (matters on small CPU hosts).
-            while (self._budget_fn is not None and not self._budget_fn()
-                   and not self._stop_evt.is_set()
-                   and not (self._gate is not None and self._gate.paused)):
-                self._stop_evt.wait(0.002)
+            with span("actor_wait"):
+                while (self._budget_fn is not None
+                       and not self._budget_fn()
+                       and not self._stop_evt.is_set()
+                       and not (self._gate is not None
+                                and self._gate.paused)):
+                    self._stop_evt.wait(0.002)
             if self._gate is not None and self._gate.paused:
                 continue  # park at the loop-top gate before rolling out
             if self._stop_evt.is_set():
@@ -248,12 +252,14 @@ class Actor(threading.Thread):
                  finished) = self._rollout(
                     self._params_fn(), env_state, obs, jnp.int32(step),
                     ep_ret, nstep, prng.chunk_key(k_roll, chunk))
-            fin = np.asarray(finished).ravel()
+            with span("host_sync"):
+                fin = np.asarray(finished).ravel()
             # n-step warm-up: invalid rows form a prefix (the window only
             # fills once), so drop them host-side — the replay thread
             # writes only real n-step rows.  One extra jit trace for the
             # single shorter chunk that spans the warm-up.
-            n_valid = int(np.asarray(valid).sum())
+            with span("host_sync"):
+                n_valid = int(np.asarray(valid).sum())
             if n_valid == 0:
                 transitions = None
             elif n_valid < chunk_len:
@@ -264,7 +270,10 @@ class Actor(threading.Thread):
                 frames=chunk_len * dqn.cfg.num_envs,
                 actor_id=self.actor_id, chunk_id=chunk,
                 completed_returns=fin[~np.isnan(fin)])
-            if not put_with_stop(self._out_q, ("block", block), self._stop_evt):
+            with span("actor_wait"):
+                delivered = put_with_stop(self._out_q, ("block", block),
+                                          self._stop_evt)
+            if not delivered:
                 return
             step += chunk_len
             chunk += 1

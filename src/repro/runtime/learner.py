@@ -32,7 +32,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs import get_registry, span
+from repro.obs import compile_count, get_registry, span
 from repro.runtime.pipeline import BatchSlab
 
 
@@ -105,6 +105,9 @@ class Learner:
         # bounded so multi-million-step runs don't grow without limit.
         self.losses: collections.deque = collections.deque(maxlen=256)
         self.first_step_time: float | None = None
+        # Compiles recorded before the first step: the run's compile
+        # count starts here, with the learner's updates.
+        self.compiles_at_first_step = 0
 
     def run(self, params, target_params, opt_m, opt_v,
             n_steps: int) -> tuple[Any, Any]:
@@ -119,8 +122,9 @@ class Learner:
                 if slab is None:
                     break
                 if self.first_step_time is None:
+                    self.compiles_at_first_step = compile_count()
                     self.first_step_time = time.perf_counter()
-                with span("learn"):
+                with span("learn", slab=slab.seq0):
                     params, opt_m, opt_v, td, loss = self._learn(
                         params, target_params, opt_m, opt_v,
                         jnp.int32(self.steps_done), slab.batch, slab.weights)
@@ -150,9 +154,10 @@ class Learner:
         return params, target_params
 
     def _get_slab(self) -> BatchSlab | None:
-        while not self._stop.is_set():
-            try:
-                return self._in_q.get(timeout=0.05)
-            except queue.Empty:
-                continue
-        return None
+        with span("learner_wait"):
+            while not self._stop.is_set():
+                try:
+                    return self._in_q.get(timeout=0.05)
+                except queue.Empty:
+                    continue
+            return None

@@ -128,11 +128,12 @@ class PrefetchPipeline(threading.Thread):
 
     def _try_put(self, slab) -> bool:
         """One bounded put attempt; abandon to the gate/stop checks."""
-        try:
-            self._out_q.put(slab, timeout=0.05)
-            return True
-        except queue.Full:
-            return False
+        with span("prefetch_wait"):
+            try:
+                self._out_q.put(slab, timeout=0.05)
+                return True
+            except queue.Full:
+                return False
 
     def _loop(self) -> None:
         seq, draw, warm = self._start_seq, self._start_draw, False
@@ -143,12 +144,16 @@ class PrefetchPipeline(threading.Thread):
                 # consuming during a snapshot, so a blocking put here
                 # would deadlock the quiesce.  The pending slab is
                 # delivered after resume — sequence numbers stay gapless.
-                self._gate.wait_if_paused(self._stop_evt)
+                with span("prefetch_wait"):
+                    self._gate.wait_if_paused(self._stop_evt)
             if pending is None:
                 state, version = self._state_fn()
                 if not warm:  # size only grows; skip the device sync once warm
-                    if int(state.size) < self._min_size:
-                        time.sleep(0.002)  # buffer not yet sampleable
+                    with span("host_sync"):
+                        size = int(state.size)
+                    if size < self._min_size:
+                        with span("prefetch_wait"):
+                            time.sleep(0.002)  # buffer not yet sampleable
                         continue
                     warm = True
                 # None (a leafless pytree, so still one jit trace) lets
@@ -156,14 +161,15 @@ class PrefetchPipeline(threading.Thread):
                 beta = (jnp.float32(self._beta_fn(version))
                         if self._beta_fn is not None else None)
                 key = prng.sample_key(self._base_key, draw)
-                with span("slab_draw"):
+                with span("slab_draw", slab=seq):
                     idx, batch, weights, stamp = self._sample(
                         state, key, beta)
                 # Publish β only once the draw has returned: a draw that
                 # raises must not leave metrics reporting the β of a
                 # slab that never existed.
                 if beta is not None:
-                    self.last_beta = float(beta)
+                    with span("host_sync"):
+                        self.last_beta = float(beta)
                 if self._probe_every and draw % self._probe_every == 0:
                     self._probe(state, key)
                 draw += 1

@@ -421,6 +421,7 @@ class ReplayService:
             if t == max(cfg.learn_start, start):
                 jax.block_until_ready(state.params)
                 t_first_learn = time.perf_counter()
+                compiles0 = obs.compile_count()
             state, m = self._agent_step(state, keys[t])
             returns.append(m["return_mean"])
             t_end = t + 1
@@ -449,6 +450,8 @@ class ReplayService:
                     break
         jax.block_until_ready(state.params)
         wall_end = time.perf_counter()
+        compiles = (obs.compile_count() - compiles0
+                    if t_first_learn is not None else 0)
         learner_steps = sum(
             1 for t in range(start, t_end)
             if t >= cfg.learn_start and t % cfg.train_every == 0)
@@ -463,6 +466,7 @@ class ReplayService:
             "learner_steps_per_sec": (learner_steps / learn_wall
                                       if learner_steps else 0.0),
             "wall_time": wall_end - t0,
+            "compiles": compiles,
             "frames": (t_end - start) * cfg.num_envs,
             "frames_per_sec": ((t_end - start) * cfg.num_envs
                                / max(wall_end - t0, 1e-9)),
@@ -693,6 +697,8 @@ class ReplayService:
                 params0, target0, opt_m0, opt_v0, n_steps)
             jax.block_until_ready(params)
             t_end = time.perf_counter()
+            compiles = (obs.compile_count()
+                        - learner.compiles_at_first_step)
         except BaseException:
             # Join first, then surface the root cause: a learner failure
             # is often secondary to a worker-thread fault, and raising
@@ -732,6 +738,9 @@ class ReplayService:
                 (learner.steps_done - start_steps) / learn_wall
                 if learner.steps_done > start_steps else 0.0),
             "wall_time": wall,
+            # Compiles from the learner's first step to the end of the
+            # run: the window of learner_steps_per_sec.
+            "compiles": compiles,
             "frames": rec["frames"],
             "total_frames": frames0 + rec["frames"],
             # Same zero-wall guard as the sync path: a run that resumes
@@ -840,7 +849,8 @@ class ReplayService:
             bstate = self._bstate
             while True:
                 try:
-                    tag, item = work_q.get(timeout=0.05)
+                    with obs.span("replay_wait"):
+                        tag, item = work_q.get(timeout=0.05)
                 except queue.Empty:
                     if stop.is_set() and learner.finished and work_q.empty():
                         return
@@ -871,9 +881,10 @@ class ReplayService:
                         # an under-count.  Stale (stamp-dropped) rows get
                         # logged too; marking them dirty just re-writes
                         # identical bytes.
-                        self._fb_rows.append(
-                            (rec["fb_applied"], np.asarray(fb.idx).ravel()))
-                    with obs.span("apply_feedback"):
+                        with obs.span("host_sync"):
+                            rows = np.asarray(fb.idx).ravel()
+                        self._fb_rows.append((rec["fb_applied"], rows))
+                    with obs.span("apply_feedback", slab=fb.seq0):
                         bstate = self._apply_feedback(
                             bstate, fb.idx, fb.td, fb.stamp)
                     self._bstate = bstate

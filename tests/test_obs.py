@@ -434,3 +434,84 @@ def test_service_without_telemetry_unchanged(tmp_path):
     assert "health" not in res.metrics
     assert not obs.get_registry().enabled
     assert os.listdir(tmp_path) == []
+
+
+# --- span arguments, compile counter, named scopes ---------------------------
+
+def test_span_args_reach_only_the_profiler_annotation():
+    """``span(name, **args)``: the histogram ignores the arguments, and
+    with profiling off no annotation is built from them."""
+    from repro.obs import tracing
+
+    reg = Registry()
+    with obs.span("unit", registry=reg, slab=12):
+        pass
+    assert reg.instruments()["span_unit_ms"].read()["count"] == 1
+    assert tracing.span("unit", registry=reg, slab=3)._annotation is None
+    prev = obs.set_registry(reg, profile=True)
+    try:
+        sp = obs.span("unit", slab=3)
+        assert isinstance(sp._annotation, jax.profiler.TraceAnnotation)
+        with sp:
+            pass
+    finally:
+        obs.set_registry(prev)
+    assert reg.instruments()["span_unit_ms"].read()["count"] == 2
+
+
+_forced = jax.jit(lambda x: x * 2.0)
+
+
+def _force_shape_on_second_learn(svc, size):
+    """Wrap the service's learner so the second call of every run also
+    runs a jitted function on ``size[0]`` elements (a new size compiles)."""
+    learn = svc._learn
+
+    def wrapped(params, target, m, v, step0, batch, weights):
+        if int(step0) == 2:            # each run counts from step 0
+            _forced(np.zeros(size[0], np.float32)).block_until_ready()
+        return learn(params, target, m, v, step0, batch, weights)
+
+    svc._learn = wrapped
+
+
+def test_compiles_counts_new_shape_in_run_and_zero_when_warm():
+    svc = ReplayService(_small_cfg(sampler="amper-fr"), num_actors=1,
+                        chunk_len=4, slab=2, max_replay_ratio=64)
+    size = [3]
+    _force_shape_on_second_learn(svc, size)
+    first = svc.run(jax.random.key(0), 12).metrics["compiles"]
+    assert first >= 1            # the learner's own first compiles count
+    assert svc.run(jax.random.key(1), 12).metrics["compiles"] == 0
+    size[0] = 5                  # a shape the forced function never saw
+    assert svc.run(jax.random.key(2), 12).metrics["compiles"] == 1
+    assert svc.run(jax.random.key(3), 12).metrics["compiles"] == 0
+    # Sync mode reports the same count over its own learning window.
+    sync = ReplayService(_small_cfg(num_envs=1), sync=True, num_actors=1)
+    sync.run(jax.random.key(0), 12)
+    assert sync.run(jax.random.key(1), 12).metrics["compiles"] == 0
+
+
+def test_compile_counter_records_into_enabled_registry_only():
+    reg = Registry()
+    with obs.use_registry(reg):              # installs the listener
+        jax.jit(lambda x: x - 1.0)(np.zeros(7, np.float32))
+        assert obs.compile_count() == 1
+        assert reg.instruments()["jit_compile_ms"].read()["count"] == 1
+    default = obs.get_registry()
+    assert not default.enabled
+    jax.jit(lambda x: x - 2.0)(np.zeros(7, np.float32))
+    assert obs.compile_count(default) == 0
+    assert obs.compile_count(reg) == 1
+
+
+def test_slab_draw_lowering_carries_named_scopes():
+    """The service's slab draw names its CSP build and FrameStore stacks
+    in the op metadata of the lowered program."""
+    cfg = _small_cfg(env="breakout", sampler="amper-fr", replay_size=512)
+    svc = ReplayService(cfg, num_actors=1, chunk_len=4, slab=2)
+    state = svc.dqn.init(jax.random.key(0)).buffer
+    text = svc._sample.lower(state, jax.random.key(1),
+                             jnp.float32(0.4)).as_text(debug_info=True)
+    for scope in ("csp_build", "csp_pick", "frame_stack", "is_weights"):
+        assert scope in text, scope
