@@ -14,8 +14,14 @@ Implements both paper variants as shape-static, jit/shard-friendly JAX:
   on the count returned by parallel range matches — the TPU-native
   replacement for the paper's k sequential best-match TCAM sensings.
 
-The CSP is a fixed-capacity index buffer (stream compaction with
-``jnp.nonzero(size=...)``), so the whole sampler jits, vmaps and shards.
+Each builder splits into a membership step (``*_members``: the CSP as a
+mask plus the rotation key) and the compaction.  :meth:`AmperSampler.sample`
+never compacts: :func:`rank_pick` rank-selects the drawn members straight
+from the mask (a count pass, then a search for ``batch`` ranks), bit-for-bit
+equal to compacting first.  :func:`build_csp_fr` / :func:`build_csp_k`
+still build the fixed-capacity index buffer (``jnp.nonzero(size=...)``) for
+callers that analyse the CSP itself.  Every path is shape-static, so the
+whole sampler jits, vmaps and shards.
 """
 from __future__ import annotations
 
@@ -114,6 +120,11 @@ def _compact(selected: jax.Array, csp_capacity: int,
     the hardware scans first.  With ``key`` we start the scan at a random
     rotation, so truncation drops a uniformly-random contiguous arc
     instead of always the same rows (unbiased in expectation).
+
+    Only :meth:`AmperSampler.build_csp` (probes, analysis, tests) builds
+    this buffer; the draw reads the same entries through :func:`rank_pick`,
+    because ``nonzero(size=...)`` lowers to a scatter of every row into
+    the buffer, which the TPU serialises.
     """
     n = selected.shape[0]
     if key is not None:
@@ -145,32 +156,34 @@ def fr_radii(v_rep: jax.Array, cfg: AmperConfig) -> jax.Array:
     return jnp.round((cfg.lam_fr / cfg.m) * vq.astype(jnp.float32)).astype(jnp.int32)
 
 
-def build_csp_fr(pq: jax.Array, valid: jax.Array, key: jax.Array,
-                 cfg: AmperConfig) -> CspResult:
-    """AMPER-fr CSP construction (Algorithm 1, lines 2-3, 9-12).
+def fr_members(pq: jax.Array, valid: jax.Array, key: jax.Array,
+               cfg: AmperConfig) -> tuple[jax.Array, jax.Array]:
+    """AMPER-fr CSP membership (Algorithm 1, lines 2-3, 9-12).
 
     Args:
       pq: int32[capacity] quantized priorities.
       valid: bool[capacity] — slot currently holds a real experience with
         non-zero priority.
-      key: PRNG key for the group representatives.
+      key: PRNG key, split into the group representatives' key and the
+        compaction rotation key ``kroll``.
+
+    Returns:
+      (selected bool[capacity], kroll).
     """
     check_modes(cfg)
     if cfg.fr_mode in ("kernel", "fused"):
         # "fused" only differs on the *sampling* path (AmperSampler.sample
         # dispatches the whole draw as one kernel); explicit CSP builds
         # share the fused-membership kernel.
-        return build_csp_fr_kernel(pq, valid, key, cfg)
+        return fr_members_kernel(pq, valid, key, cfg)
     kv, kroll = jax.random.split(key)
     v_rep = group_representatives(kv, cfg)
     if cfg.fr_mode == "interval":
         lo, hi = fr_intervals(v_rep, cfg)
-        selected = _interval_membership(pq, lo, hi) & valid
-        return _compact(selected, cfg.csp_capacity, kroll)
+        return _interval_membership(pq, lo, hi) & valid, kroll
     if cfg.fr_mode == "window":
         lo, hi = fr_intervals(v_rep, cfg)
-        selected = _window_membership(pq, lo, hi, cfg) & valid
-        return _compact(selected, cfg.csp_capacity, kroll)
+        return _window_membership(pq, lo, hi, cfg) & valid, kroll
     if cfg.exact_radius:
         vq = qz.quantize(v_rep, cfg.v_max, cfg.frac_bits)
         radius = fr_radii(v_rep, cfg)
@@ -178,7 +191,13 @@ def build_csp_fr(pq: jax.Array, valid: jax.Array, key: jax.Array,
     else:
         vq, mask = fr_queries(v_rep, cfg)
         match = qz.ternary_match(pq[None, :], vq[:, None], mask[:, None])
-    selected = jnp.any(match, axis=0) & valid
+    return jnp.any(match, axis=0) & valid, kroll
+
+
+def build_csp_fr(pq: jax.Array, valid: jax.Array, key: jax.Array,
+                 cfg: AmperConfig) -> CspResult:
+    """AMPER-fr CSP: :func:`fr_members` compacted into the index buffer."""
+    selected, kroll = fr_members(pq, valid, key, cfg)
     return _compact(selected, cfg.csp_capacity, kroll)
 
 
@@ -282,12 +301,14 @@ def _window_membership(pq: jax.Array, lo: jax.Array, hi: jax.Array,
     return sel
 
 
-def build_csp_fr_kernel(pq: jax.Array, valid: jax.Array, key: jax.Array,
-                        cfg: AmperConfig) -> CspResult:
-    """AMPER-fr via the fused Pallas multi-query kernel (one HBM pass).
+def fr_members_kernel(pq: jax.Array, valid: jax.Array, key: jax.Array,
+                      cfg: AmperConfig) -> tuple[jax.Array, jax.Array]:
+    """AMPER-fr membership via the fused Pallas multi-query kernel (one
+    HBM pass).
 
-    Bit-identical to :func:`build_csp_fr`: a prefix query with don't-care
-    mask M is exactly the inclusive range [q & ~M, (q & ~M) | M].
+    Bit-identical to the broadcast search of :func:`fr_members`: a prefix
+    query with don't-care mask M is exactly the inclusive range
+    [q & ~M, (q & ~M) | M].
     """
     from repro.kernels import ops as kops  # deferred: kernels are optional
 
@@ -301,7 +322,14 @@ def build_csp_fr_kernel(pq: jax.Array, valid: jax.Array, key: jax.Array,
         vq, mask = fr_queries(v_rep, cfg)
         lo, hi = qz.prefix_range(vq, mask)
     sel, _counts = kops.multi_query_match(pq, valid, lo, hi)
-    return _compact(sel, cfg.csp_capacity, kroll)
+    return sel, kroll
+
+
+def build_csp_fr_kernel(pq: jax.Array, valid: jax.Array, key: jax.Array,
+                        cfg: AmperConfig) -> CspResult:
+    """AMPER-fr CSP: :func:`fr_members_kernel` compacted into the buffer."""
+    selected, kroll = fr_members_kernel(pq, valid, key, cfg)
+    return _compact(selected, cfg.csp_capacity, kroll)
 
 
 def _knn_select_hist(pq: jax.Array, valid: jax.Array, vq: jax.Array,
@@ -350,9 +378,10 @@ def _knn_select_hist(pq: jax.Array, valid: jax.Array, vq: jax.Array,
     return within & (order <= n_i[:, None])
 
 
-def build_csp_k(pq: jax.Array, valid: jax.Array, key: jax.Array,
-                cfg: AmperConfig) -> CspResult:
-    """AMPER-k CSP construction (Algorithm 1, lines 2-8)."""
+def k_members(pq: jax.Array, valid: jax.Array, key: jax.Array,
+              cfg: AmperConfig) -> tuple[jax.Array, jax.Array]:
+    """AMPER-k CSP membership (Algorithm 1, lines 2-8): (selected, kroll)
+    under the key split of :func:`fr_members`."""
     kv, kroll = jax.random.split(key)
     v_rep = group_representatives(kv, cfg)
     vq = qz.quantize(v_rep, cfg.v_max, cfg.frac_bits)
@@ -364,7 +393,13 @@ def build_csp_k(pq: jax.Array, valid: jax.Array, key: jax.Array,
         sel = _knn_select_hist(pq, valid, vq, n_i, cfg.frac_bits)
     else:
         sel = _knn_select_sort(pq, valid, vq, n_i)
-    selected = jnp.any(sel, axis=0) & valid
+    return jnp.any(sel, axis=0) & valid, kroll
+
+
+def build_csp_k(pq: jax.Array, valid: jax.Array, key: jax.Array,
+                cfg: AmperConfig) -> CspResult:
+    """AMPER-k CSP: :func:`k_members` compacted into the index buffer."""
+    selected, kroll = k_members(pq, valid, key, cfg)
     return _compact(selected, cfg.csp_capacity, kroll)
 
 
@@ -399,6 +434,52 @@ def sample_from_csp(csp: CspResult, key: jax.Array, batch: int,
     fallback = pick_uniform(jax.random.bits(k_fb, (batch,), jnp.uint32),
                             fallback_size)
     return jnp.where(csp.count > 0, picked, fallback).astype(jnp.int32)
+
+
+def _rank_block(n: int) -> int:
+    """Block length of :func:`rank_pick`: the power of two at or above
+    sqrt(n), so the block search and the in-block search cost alike; at
+    least one 128-lane row."""
+    return max(128, 1 << ((max(n - 1, 1).bit_length() + 1) // 2))
+
+
+def rank_pick(selected: jax.Array, kroll: jax.Array, key: jax.Array,
+              batch: int, csp_capacity: int,
+              fallback_size: jax.Array) -> jax.Array:
+    """:func:`sample_from_csp` of ``_compact(selected, csp_capacity, kroll)``
+    without building the compacted buffer; bit-identical.
+
+    The buffer rotated by ``shift`` holds at slot u the member whose
+    index-order rank is ``(u + s_shift) % total`` (``s_shift`` = members
+    below ``shift``), also when truncated to ``csp_capacity``, since every
+    draw is below the truncated count.  So one pass counts the members of
+    each block of the mask, the blocks' running counts locate each drawn
+    rank's block, and a running count within that block's row its lane.
+    """
+    n = selected.shape[0]
+    shift = jax.random.randint(kroll, (), 0, n)
+    block = _rank_block(n)
+    n_blocks = -(-n // block)
+    sel = jnp.pad(selected, (0, n_blocks * block - n)).reshape(n_blocks, block)
+    pos = jnp.arange(n_blocks * block, dtype=jnp.int32).reshape(n_blocks, block)
+    block_counts = jnp.sum(sel, axis=1, dtype=jnp.int32)
+    s_shift = jnp.sum(sel & (pos < shift), dtype=jnp.int32)
+    total = jnp.sum(block_counts)
+    count = jnp.minimum(total, csp_capacity)
+
+    k_pick, k_fb = jax.random.split(key)
+    u = pick_uniform(jax.random.bits(k_pick, (batch,), jnp.uint32), count)
+    rank = (u + s_shift) % jnp.maximum(total, 1)
+    ends = jnp.cumsum(block_counts)
+    blk = jnp.minimum(jnp.sum(ends[None, :] <= rank[:, None], axis=1,
+                              dtype=jnp.int32), n_blocks - 1)
+    in_block = rank - (ends[blk] - block_counts[blk])
+    row_ends = jnp.cumsum(sel[blk], axis=1, dtype=jnp.int32)
+    lane = jnp.sum(row_ends <= in_block[:, None], axis=1, dtype=jnp.int32)
+    picked = blk * block + lane
+    fallback = pick_uniform(jax.random.bits(k_fb, (batch,), jnp.uint32),
+                            fallback_size)
+    return jnp.where(count > 0, picked, fallback).astype(jnp.int32)
 
 
 class AmperState(NamedTuple):
@@ -444,21 +525,32 @@ class AmperSampler:
         valid = state.valid.at[idx].set(priority > 0)
         return AmperState(pq=pq, valid=valid)
 
-    def build_csp(self, state: AmperState, key: jax.Array) -> CspResult:
-        fn = build_csp_fr if self.variant == "fr" else build_csp_k
+    def members(self, state: AmperState,
+                key: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """CSP membership mask and compaction rotation key."""
+        fn = fr_members if self.variant == "fr" else k_members
         with jax.named_scope("csp_build"):
             return fn(state.pq, state.valid, key, self.cfg)
 
+    def build_csp(self, state: AmperState, key: jax.Array) -> CspResult:
+        selected, kroll = self.members(state, key)
+        with jax.named_scope("csp_build"):
+            return _compact(selected, self.cfg.csp_capacity, kroll)
+
     def sample(self, state: AmperState, key: jax.Array, batch: int,
                stratified: bool = True) -> jax.Array:
+        """``sample_from_csp(build_csp(state, kcsp), kpick, ...)`` with
+        ``kcsp, kpick = split(key)``, drawn by :func:`rank_pick` (or the
+        fused kernel) without building the CSP buffer."""
         del stratified  # CSP sampling is uniform by construction
         kcsp, kpick = jax.random.split(key)
         if self.variant == "fr" and self.cfg.fr_mode == "fused":
             return self._sample_fused(state, kcsp, kpick, batch)
-        csp = self.build_csp(state, kcsp)
+        selected, kroll = self.members(state, kcsp)
         with jax.named_scope("csp_pick"):
             live = jnp.sum(state.valid.astype(jnp.int32))
-            return sample_from_csp(csp, kpick, batch, live)
+            return rank_pick(selected, kroll, kpick, batch,
+                             self.cfg.csp_capacity, live)
 
     def _sample_fused(self, state: AmperState, kcsp: jax.Array,
                       kpick: jax.Array, batch: int) -> jax.Array:

@@ -1,9 +1,11 @@
 """Fused AMPER-fr sampling: the whole draw in one Pallas dispatch.
 
-The reference path (``fr_mode="broadcast"``) runs Algorithm 1 as separate
-XLA ops: quantized m-range TCAM match -> stream compaction of the CSP
-(``nonzero`` after a random rotation) -> uniform counter draw -> index
-gather.  This kernel is the paper's Fig. 3 pipeline as ONE pass machine:
+The reference law (``build_csp`` + ``sample_from_csp``) runs Algorithm 1
+as separate XLA ops: quantized m-range TCAM match -> stream compaction of
+the CSP (``nonzero`` after a random rotation) -> uniform counter draw ->
+index gather; the XLA draw path (``amper.rank_pick``) rank-selects from
+the mask instead, by the identity below.  This kernel is the paper's
+Fig. 3 pipeline as ONE pass machine:
 
 * phase 0 streams the (rows, 128) priority table once, evaluating the
   m-range match per tile and accumulating three scalars in SMEM — the

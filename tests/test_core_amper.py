@@ -162,3 +162,91 @@ def test_unknown_search_mode_raises(field):
     with pytest.raises(ValueError, match=f"unknown {field}"):
         build_csp_fr(jnp.zeros(256, jnp.int32), jnp.ones(256, bool),
                      jax.random.key(0), cfg)
+
+
+_SAMPLE_MODES = ([("fr", {"fr_mode": m})
+                  for m in ("broadcast", "interval", "window", "kernel")]
+                 + [("k", {"knn_mode": m})
+                    for m in ("sort", "bisect", "hist")])
+_SAMPLE_CASES = ("plain", "truncated", "empty", "all_valid", "shift_first",
+                 "shift_last", "batch_over_count", "ragged")
+
+
+def _key_with_shift(n: int, target: int) -> jax.Array:
+    """A draw key whose compaction rotation (kcsp -> kroll) is ``target``."""
+    keys = jax.vmap(lambda i: jax.random.fold_in(jax.random.key(11), i))(
+        jnp.arange(40_000))
+
+    def shift(k):
+        kroll = jax.random.split(jax.random.split(k)[0])[1]
+        return jax.random.randint(kroll, (), 0, n)
+
+    hits = np.flatnonzero(np.asarray(jax.vmap(shift)(keys)) == target)
+    assert hits.size, f"no key among 40,000 rotates by {target}"
+    return keys[int(hits[0])]
+
+
+@pytest.mark.parametrize("case", _SAMPLE_CASES)
+@pytest.mark.parametrize("variant,mode", _SAMPLE_MODES,
+                         ids=[m for _, d in _SAMPLE_MODES for m in d.values()])
+def test_sample_equals_compacted_csp(variant, mode, case):
+    """The draw rank-selects members from the mask; it must equal the
+    compacted-buffer draw ``sample_from_csp(build_csp(...))`` under the
+    same key tree, in every search mode and at every edge of the pick."""
+    from repro.core.amper import _rank_block
+
+    n = 1000 if case == "ragged" else 1024
+    assert (n % _rank_block(n) != 0) == (case == "ragged")
+    batch = 64
+    csp_capacity = {"truncated": 16, "batch_over_count": 3}.get(case, n)
+    c = AmperConfig(capacity=n, m=8, lam=0.15, lam_fr=2.0, v_max=1.0,
+                    csp_capacity=csp_capacity, **mode)
+    s = AmperSampler(c, variant)
+    p = jax.random.uniform(jax.random.key(2), (n,)) + 0.01
+    valid = {"empty": jnp.zeros(n, bool), "all_valid": jnp.ones(n, bool)}.get(
+        case, jax.random.bernoulli(jax.random.key(3), 0.7, (n,)))
+    st = s.update(s.init(), jnp.arange(n), jnp.where(valid, p, 0.0))
+    key = {"shift_first": lambda: _key_with_shift(n, 0),
+           "shift_last": lambda: _key_with_shift(n, n - 1)}.get(
+        case, lambda: jax.random.key(4))()
+
+    @jax.jit
+    def compacted(state, k):
+        kcsp, kpick = jax.random.split(k)
+        csp = s.build_csp(state, kcsp)
+        live = jnp.sum(state.valid.astype(jnp.int32))
+        return (sample_from_csp(csp, kpick, batch, live), csp.count,
+                jnp.sum(csp.selected))
+
+    got = jax.jit(lambda state, k: s.sample(state, k, batch))(st, key)
+    want, count, members = compacted(st, key)
+    count, members = int(count), int(members)
+    assert {"empty": count == 0,
+            "truncated": members > count == csp_capacity,
+            "batch_over_count": 0 < count < batch}.get(
+        case, 0 < count == members), (case, count, members)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_draw_lowers_without_csp_buffer():
+    """At the benchmark's size (1M rows, CSP capacity 150,000, a slab of
+    256 draws) the draw holds no scatter and no CSP-sized buffer: the
+    ``nonzero`` compaction it replaced was a 1M-update scatter."""
+    import re
+
+    n, csp_capacity, batch = 1_000_000, 150_000, 256
+    s = AmperSampler(AmperConfig(capacity=n, m=20, lam_fr=2.0, v_max=8.0,
+                                 csp_capacity=csp_capacity), "fr")
+    state = type(s.init())(jax.ShapeDtypeStruct((n,), jnp.int32),
+                           jax.ShapeDtypeStruct((n,), jnp.bool_))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    buffer = re.compile(rf"tensor<(\d+x)*{csp_capacity}[x>]")
+
+    def lowered(fn):
+        return jax.jit(fn).lower(state, key).as_text()
+
+    draw = lowered(lambda st, k: s.sample(st, k, batch))
+    assert "scatter" not in draw
+    assert not buffer.search(draw)
+    # the pattern does find the buffer where it is built
+    assert buffer.search(lowered(lambda st, k: s.build_csp(st, k).indices))
