@@ -55,13 +55,12 @@ def _flat_slab(x):
     return x.swapaxes(0, 1).reshape((-1,) + x.shape[2:])
 
 
-def _table(sampler: str, sstate, capacity: int):
-    """Host view of a sampler state: (pq, valid) or float priorities."""
-    if sampler.startswith("amper"):
-        return {"pq": _host(sstate.pq), "valid": _host(sstate.valid)}
-    tree = _host(sstate.tree)
-    leaf0 = 1 << max(capacity - 1, 0).bit_length()
-    return {"prios": tree[leaf0:leaf0 + capacity].astype(np.float64)}
+def _table(c, t: dict) -> dict:
+    """Host view of a recorded sampler table (``drive.Keep``): (pq, valid)
+    or float priorities."""
+    if c.quant:
+        return {"pq": _host(t["pq"]), "valid": _host(t["valid"])}
+    return {"prios": _host(t["prios"]).astype(np.float64)}
 
 
 class Cell:
@@ -98,19 +97,21 @@ class Cell:
             kw["shards"] = self.shards
         return self.ref.draw(t["pq"], t["valid"], key, n, **kw)
 
-    def materialize(self, state, idx, dtype=np.float32):
-        ring = {k: _host(v) for k, v in state.storage.items()}
+    def materialize(self, view, idx, dtype=np.float32):
+        """The reference batch at ``idx`` from a recorded view, whose
+        ``rows`` are in ``idx``'s order."""
         return ref_frames.materialize(
-            ring, _host(state.write_stamp), int(state.size), idx,
+            view["rows"], idx, int(view["size"]), capacity=self.cap,
             history_len=self.hist, stride=self.stride, n_step=self.n_step,
             gamma=self.gamma, scale=1.0 / 255.0, dtype=dtype)
 
 
-def _draw_numbers(c: Cell, state, key, beta, idx, batch, w, control):
-    """draw / weights / stacks numbers of one draw of ``len(idx)`` rows."""
-    t = _table(c.sampler, state.sampler_state, c.cap)
+def _draw_numbers(c: Cell, view, key, beta, idx, batch, w, control):
+    """draw / weights / stacks numbers of one draw of ``len(idx)`` rows;
+    ``view`` is the draw's recorded ``drive.Keep.view``."""
+    t = _table(c, view["table"])
     prios = c.prios(t)
-    size = int(state.size)
+    size = int(view["size"])
     out = {}
     if c.quant:
         want = c.law_draw(t, key, len(idx))
@@ -123,18 +124,17 @@ def _draw_numbers(c: Cell, state, key, beta, idx, batch, w, control):
     got_w = c.ref.weights(prios, idx, size, beta, BF16) if control else w
     out["weights"] = float(np.max(np.abs(np.asarray(got_w, np.float64)
                                          - want_w) / want_w))
-    want_b = c.materialize(state, idx)
-    got_b = c.materialize(state, idx, BF16) if control else batch
+    want_b = c.materialize(view, idx)
+    got_b = c.materialize(view, idx, BF16) if control else batch
     out["stacks"] = ref_frames.gap(got_b, want_b)
     return out, want_b, want_w
 
 
-def _writeback_number(c: Cell, before, after, idx, abs_td, stamp, control):
-    t0 = _table(c.sampler, before.sampler_state, c.cap)
-    live = None
-    if stamp is not None:
-        live = ((_host(before.write_stamp)[idx] == stamp[:, 0])
-                & (_host(before.write_gen)[idx] == stamp[:, 1]))
+def _writeback_number(c: Cell, before, after, idx, abs_td, live, control):
+    """Gap of the table written back over ``idx``: ``before`` and
+    ``after`` are recorded tables, ``live`` the rows whose sample-time
+    stamps still held (None: a write without stamps)."""
+    t0 = _table(c, before)
     want, allowed = ref_wb.new_priorities(
         c.prios(t0), idx, abs_td, alpha=c.alpha, eps=c.eps, live=live)
     if control:
@@ -142,7 +142,7 @@ def _writeback_number(c: Cell, before, after, idx, abs_td, stamp, control):
                                        alpha=c.alpha, eps=c.eps, live=live,
                                        dtype=BF16)
     else:
-        got = c.prios(_table(c.sampler, after.sampler_state, c.cap))
+        got = c.prios(_table(c, after))
     if c.quant:
         want = np.minimum(want, c.v_max)
         allowed = {r: {min(v, c.v_max) for v in vs}
@@ -209,19 +209,23 @@ def service_numbers(c: Cell, conf: dict, rec: dict, control: bool) -> dict:
         idx, batch, w, _stamp = d["out"]
         idx_f = _flat_slab(idx)
         batch_f = {k: _flat_slab(v) for k, v in batch.items()}
+        view = {**d["view"], "rows": {k: _flat_slab(v) for k, v in
+                                      d["view"]["rows"].items()}}
         beta = float(d["beta"]) if d["beta"] is not None else c.beta
         nums, want_b, want_w = _draw_numbers(
-            c, d["state"], d["key"], beta, idx_f, batch_f, _flat_slab(w),
+            c, view, d["key"], beta, idx_f, batch_f, _flat_slab(w),
             control)
         keep(nums)
         if i == 0:
             learn_in = (want_b, want_w)
     keep(_learner_numbers(c, conf, rec, *learn_in, control))
     fb = rec["fb0"]
+    before, stamp = fb["before"], _host(fb["stamp"]).reshape(-1, 2)
+    live = ((_host(before["write_stamp"]).reshape(-1) == stamp[:, 0])
+            & (_host(before["write_gen"]).reshape(-1) == stamp[:, 1]))
     keep({"priorities": _writeback_number(
-        c, fb["before"], fb["after"], _host(fb["idx"]).reshape(-1),
-        np.abs(_host(fb["td"]).reshape(-1)),
-        _host(fb["stamp"]).reshape(-1, 2), control)})
+        c, before["table"], fb["after"], _host(fb["idx"]).reshape(-1),
+        np.abs(_host(fb["td"]).reshape(-1)), live, control)})
     return worst
 
 
@@ -233,11 +237,11 @@ def draw_loop_numbers(c: Cell, conf: dict, rec: dict, control: bool) -> dict:
                          {k: _host(v) for k, v in x.items()}
                          for x in d["out"])
         key = jax.random.fold_in(rec["key"], i)
-        nums, _, _ = _draw_numbers(c, d["state"], key, c.beta, idx, batch,
+        nums, _, _ = _draw_numbers(c, d["view"], key, c.beta, idx, batch,
                                    w, control)
         nums["priorities"] = _writeback_number(
-            c, d["state"], d["after"], idx, pool[i % len(pool)], None,
-            control)
+            c, d["view"]["table"], d["after"], idx, pool[i % len(pool)],
+            None, control)
         for k, v in nums.items():
             worst[k] = max(worst.get(k, 0.0), v)
     return worst

@@ -14,23 +14,126 @@ timed from its dispatch until its indices, weights and stacked batch are
 ready, so writes queued ahead of it count in its time.
 
 Both keep, for the check, what the timed path produced on a few steps
-picked from the seed, with the state those steps read.
+picked from the seed, and copies of what the reference reads of the state
+those steps read (``Keep``): never the state itself, which a program that
+donates its replay state deletes at its next write.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from bench import generator
+from bench.reference import frames as ref_frames
+
+# The ring's keys the frame law reads (the write stamps are kept with them).
+_ROW_KEYS = ("frame", "done", "reward", "action")
 
 
 def _copy(tree):
     return jax.tree.map(jnp.copy, tree)
+
+
+def _table(sstate, capacity: int) -> dict:
+    """The sampler table the check reads: AMPER's quantized priorities and
+    validity, or the sum tree's leaves."""
+    if hasattr(sstate, "pq"):
+        return {"pq": sstate.pq, "valid": sstate.valid}
+    leaf0 = 1 << max(capacity - 1, 0).bit_length()
+    return {"prios": sstate.tree[leaf0:leaf0 + capacity]}
+
+
+def _take(x, slots, perm):
+    """``x[slots]`` along axis 0, for slots in range.  A leaf of more than
+    one axis is read a row at a time, by dynamic slices of ``x`` seen in
+    its device layout's own axis order (``perm``, major to minor), so that
+    XLA never re-lays the ring out: a plain gather of rows from a uint8 ring
+    of 84x84 frames on a v5e first copies the whole ring into another
+    layout (5.6 GB for 500,000 rows, AOT)."""
+    if x.ndim == 1:
+        return x[slots]
+    flat = slots.reshape(-1)
+    xt = jnp.transpose(x, perm)  # the layout's own order: no copy
+    ax = perm.index(0)
+
+    def row(i, out):
+        r = jax.lax.dynamic_slice_in_dim(xt, flat[i], 1, ax)
+        return jax.lax.dynamic_update_slice_in_dim(out, r, i, ax)
+
+    out = jax.lax.fori_loop(0, flat.shape[0], row, jnp.zeros(
+        xt.shape[:ax] + flat.shape + xt.shape[ax + 1:], x.dtype))
+    back = tuple(int(a) for a in np.argsort(perm))
+    return jnp.transpose(out, back).reshape(slots.shape + x.shape[1:])
+
+
+def _take_sharded(sharding, x, slots, perm):
+    """``_take`` of a leaf sharded on axis 0: each shard reads the slots it
+    holds, and a sum over the shards puts them together (a loop of slices
+    across shards would gather the whole ring to every chip)."""
+    axes = sharding.spec[0]
+
+    def local(x, slots):
+        n = x.shape[0]
+        loc = slots - jax.lax.axis_index(axes) * n
+        mine = (loc >= 0) & (loc < n)
+        got = _take(x, jnp.where(mine, loc, 0), perm)
+        mine = mine.reshape(mine.shape + (1,) * (got.ndim - mine.ndim))
+        return jax.lax.psum(jnp.where(mine, got, 0), axes)
+
+    return jax.shard_map(local, mesh=sharding.mesh, in_specs=(P(axes), P()),
+                         out_specs=P(), check_vma=False)(x, slots)
+
+
+class Keep:
+    """Jitted device copies of what the check reads of a replay state, so
+    that nothing of the state is held past the call that recorded it.
+
+    ``view(state, idx)``: the sampler table, ``size`` and, for every row
+    ``a`` of ``idx``, the ring's rows at the slots ``a + k*stride`` (mod
+    the capacity) for ``k`` in ``frames.window``: ``[*idx.shape, K, ...]``
+    per key.  ``fed(state, idx)``: the table and the write stamps at
+    ``idx`` before a write-back.  ``table(state)``: the table alone."""
+
+    def __init__(self, replay):
+        fs, cap = replay.frame_store, replay.capacity
+        steps = ref_frames.window(fs.history_len, fs.n_step) * fs.stride
+        sharding = replay.storage_sharding
+        take = (_take if sharding is None
+                else functools.partial(_take_sharded, sharding))
+
+        def keep_view(state, idx, perms):
+            perms = dict(perms)
+            slots = (idx[..., None] + jnp.asarray(steps, idx.dtype)) % cap
+            ring = {k: state.storage[k] for k in _ROW_KEYS}
+            ring.update(write_stamp=state.write_stamp,
+                        write_gen=state.write_gen)
+            return {"table": _table(state.sampler_state, cap),
+                    "size": state.size,
+                    "rows": {k: take(v, slots, perms.get(k, (0,)))
+                             for k, v in ring.items()}}
+
+        def keep_fed(state, idx):
+            return {"table": _table(state.sampler_state, cap),
+                    "write_stamp": state.write_stamp[idx],
+                    "write_gen": state.write_gen[idx]}
+
+        def keep_table(state):
+            return _table(state.sampler_state, cap)
+
+        self._view = jax.jit(keep_view, static_argnums=2)
+        self.fed, self.table = jax.jit(keep_fed), jax.jit(keep_table)
+
+    def view(self, state, idx):
+        perms = tuple((k, tuple(state.storage[k].format.layout.major_to_minor))
+                      for k in _ROW_KEYS if state.storage[k].ndim > 1)
+        return self._view(state, idx, perms)
 
 
 class _Annotation:
@@ -53,12 +156,14 @@ class ServiceRecorder:
     """Wraps a service's jitted draw, learner and write-back stages.
 
     Counts the learner's updates and times the window on the learner
-    thread, and keeps what the check reads: picked slab draws (state,
-    key, beta and copies of the outputs, which the learner later
-    donates), the first learner call (its inputs and outputs) and the
-    write-back of that call's TD errors (state before and after)."""
+    thread, and keeps what the check reads: picked slab draws (the
+    ``Keep.view`` of the drawn rows, key, beta and copies of the outputs,
+    which the learner later donates), the first learner call (its inputs
+    and outputs) and the write-back of that call's TD errors (the table
+    and stamps before, the table after)."""
 
     def __init__(self, svc):
+        self._keep = Keep(svc.dqn.replay)
         self._sample, self._learn = svc._sample, svc._learn
         self._feedback = svc._apply_feedback
         svc._sample, svc._learn = self.sample, self.learn
@@ -77,8 +182,8 @@ class ServiceRecorder:
         i = self.draw_n
         self.draw_n += 1
         if i in self.picks:
-            self.draws[i] = {"state": state, "key": key, "beta": beta,
-                             "out": _copy(out)}
+            self.draws[i] = {"view": self._keep.view(state, out[0]),
+                             "key": key, "beta": beta, "out": _copy(out)}
         return out
 
     def learn(self, params, target, m, v, step0, batch, weights):
@@ -103,11 +208,12 @@ class ServiceRecorder:
         rec = self.learn0 is not None and self.fb0 is None and \
             td is self.learn0["td_obj"]
         if rec:
-            self.fb0 = {"before": state, "idx": jnp.copy(idx),
-                        "td": jnp.copy(td), "stamp": jnp.copy(stamp)}
+            self.fb0 = {"before": self._keep.fed(state, idx),
+                        "idx": jnp.copy(idx), "td": jnp.copy(td),
+                        "stamp": jnp.copy(stamp)}
         out = self._feedback(state, idx, td, stamp)
         if rec:
-            self.fb0["after"] = out
+            self.fb0["after"] = self._keep.table(out)
         return out
 
 
@@ -130,9 +236,16 @@ def _service(cell, key, seconds: float, trace_on: bool, profile):
     prefill = generator.make_prefill(
         dqn.replay, dqn.example_transition, cfg.num_envs, dqn.env.n_actions,
         traffic["prefill"])
-    filled = jax.block_until_ready(prefill(k_fill))
+    # Set-up runs start from copies of the prefilled ring; the window's run
+    # is handed the ring itself, and the harness keeps no reference to it.
+    fill = {"state": jax.block_until_ready(prefill(k_fill)), "hand": False}
     init = dqn.init
-    svc.dqn = dqn._replace(init=lambda k: init(k)._replace(buffer=filled))
+
+    def init_filled(k):
+        buf = fill.pop("state") if fill["hand"] else _copy(fill["state"])
+        return init(k)._replace(buffer=buf)
+
+    svc.dqn = dqn._replace(init=init_filled)
     rec = ServiceRecorder(svc)
     slab = svc.slab
     run_keys = jax.random.split(k_run, 4)
@@ -153,6 +266,7 @@ def _service(cell, key, seconds: float, trace_on: bool, profile):
     picks = {0, *rng.choice(np.arange(1, calls),
                             size=min(traffic["check_slabs"] - 1, calls - 1),
                             replace=False).tolist()}
+    fill["hand"] = True
     t_setup = time.perf_counter()
     with profile():
         res, rate = run(run_keys[3], calls, picks, annotate=True)
@@ -166,6 +280,23 @@ def _service(cell, key, seconds: float, trace_on: bool, profile):
         "shards": getattr(dqn.replay.sampler, "n_shards", 1),
         "draws": rec.draws, "learn0": rec.learn0, "fb0": rec.fb0,
         "slab": slab}
+
+
+def _draw_programs(rb, batch: int, n_td: int, n_ins: int):
+    """The client's jitted draw, write-back and insert."""
+
+    def replay_draw(s, k, i):
+        return rb.sample(s, jax.random.fold_in(k, i), batch)
+
+    def replay_write(s, idx, pool, i):
+        return rb.update_priorities(s, idx, pool[i % n_td])
+
+    def replay_insert(s, pool, j):
+        step = jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, j % n_ins, 0), pool)
+        return rb.add_block(s, step, aggregated=True)
+
+    return jax.jit(replay_draw), jax.jit(replay_write), jax.jit(replay_insert)
 
 
 def _draw_loop(cell, key, seconds: float, trace_on: bool, profile):
@@ -185,20 +316,8 @@ def _draw_loop(cell, key, seconds: float, trace_on: bool, profile):
         dqn.example_transition, cfg.num_envs, dqn.env.n_actions, batch, p,
         n_ins, n_td)(k_pool))
     every = traffic["draws_per_insert"]
-
-    def replay_draw(s, k, i):
-        return rb.sample(s, jax.random.fold_in(k, i), batch)
-
-    def replay_write(s, idx, pool, i):
-        return rb.update_priorities(s, idx, pool[i % n_td])
-
-    def replay_insert(s, pool, j):
-        step = jax.tree.map(
-            lambda x: jax.lax.dynamic_index_in_dim(x, j % n_ins, 0), pool)
-        return rb.add_block(s, step, aggregated=True)
-
-    draw, write = jax.jit(replay_draw), jax.jit(replay_write)
-    insert = jax.jit(replay_insert)
+    draw, write, insert = _draw_programs(rb, batch, n_td, n_ins)
+    keep = Keep(rb)
     ann = (jax.profiler.TraceAnnotation if trace_on
            else lambda _n: contextlib.nullcontext())
     records = {}
@@ -206,20 +325,20 @@ def _draw_loop(cell, key, seconds: float, trace_on: bool, profile):
     def loop(state, t_stop, n_max, picks, lat):
         i = 0
         while i < n_max and time.perf_counter() < t_stop:
-            before = state
             t0 = time.perf_counter()
             with ann("draw"):
                 out = draw(state, k_draw, i)
                 jax.block_until_ready(out)
             lat.append(time.perf_counter() - t0)
+            if i in picks:  # before the write, which may consume state
+                records[i] = {"view": keep.view(state, out[0]), "out": out}
             with ann("write"):
                 state = write(state, out[0], td_pool, i)
-            written = state
+            if i in picks:
+                records[i]["after"] = keep.table(state)
             if i % every == every - 1:
                 with ann("insert"):
                     state = insert(state, ins_pool, i // every)
-            if i in picks:
-                records[i] = {"state": before, "out": out, "after": written}
             i += 1
         return state, i
 

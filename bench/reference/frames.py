@@ -16,23 +16,36 @@ write stamp ``s``:
   window ran through without an end and that row is in sequence; otherwise
   the transition is terminal (``next_obs`` zero, ``terminated`` 1).
 
-Frames scale to float as ``uint8 * scale``.
+Every slot these read lies at ``a + k*stride`` (mod the capacity) for ``k``
+in :func:`window`, so the law reads the ring's rows there and nothing else
+of it.  Frames scale to float as ``uint8 * scale``.
 """
 from __future__ import annotations
 
 import numpy as np
 
 
-def materialize(ring: dict, stamp, size: int, idx, *, history_len: int,
-                stride: int, n_step: int, gamma: float, scale: float,
-                dtype=np.float32) -> dict:
-    frame = np.asarray(ring["frame"])
-    done = np.asarray(ring["done"], np.float32)
-    reward = np.asarray(ring["reward"], np.float32)
-    stamp = np.asarray(stamp, np.int64)
-    cap = len(stamp)
-    a = np.asarray(idx, np.int64) % cap
-    ref = stamp[a]
+def window(history_len: int, n_step: int) -> np.ndarray:
+    """The steps ``k`` around an anchor whose slots the law reads: the
+    oldest frame of ``obs`` up to the bootstrap row."""
+    return np.arange(1 - history_len, n_step + 1)
+
+
+def materialize(rows: dict, idx, size: int, *, capacity: int,
+                history_len: int, stride: int, n_step: int, gamma: float,
+                scale: float, dtype=np.float32) -> dict:
+    """The reference batch at anchors ``idx``.  ``rows`` holds the ring's
+    ``frame``, ``done``, ``reward``, ``action`` and ``write_stamp`` at the
+    slots ``(idx + k*stride) % capacity``, one column per ``k`` of
+    :func:`window`: ``[len(idx), len(window), ...]``."""
+    frame = np.asarray(rows["frame"])
+    done = np.asarray(rows["done"], np.float32)
+    reward = np.asarray(rows["reward"], np.float32)
+    stamp = np.asarray(rows["write_stamp"], np.int64)
+    a = np.asarray(idx, np.int64) % capacity
+    col = lambda k: k + history_len - 1  # noqa: E731
+    slot = lambda k: (a + k * stride) % capacity  # noqa: E731
+    ref = stamp[:, col(0)]
     sc = np.float32(scale)
 
     def cast(x):
@@ -42,31 +55,32 @@ def materialize(ring: dict, stamp, size: int, idx, *, history_len: int,
 
         return np.asarray(np.asarray(x, ml_dtypes.bfloat16), np.float32)
 
-    def stack(end, end_stamp, ok):
+    def stack(end, ok):
+        end_stamp = stamp[:, col(end)]
         out = []
         for j in range(history_len):
-            slot = (end - j * stride) % cap
+            k = col(end - j)
             if j:
-                ok = (ok & (stamp[slot] - end_stamp == -j * stride)
-                      & (slot < size) & (done[slot] < 0.5))
-            f = cast(frame[slot].astype(np.float32) * sc)
+                ok = (ok & (stamp[:, k] - end_stamp == -j * stride)
+                      & (slot(end - j) < size) & (done[:, k] < 0.5))
+            f = cast(frame[:, k].astype(np.float32) * sc)
             out.append(f * ok.reshape(ok.shape + (1,) * (f.ndim - 1)))
         return np.stack(out[::-1], axis=-1)
 
     written = a < size
-    obs = stack(a, ref, written)
+    obs = stack(0, written)
     enter = written.copy()
     ret = np.zeros(len(a), np.float32)
     for k in range(n_step):
-        slot = (a + k * stride) % cap
-        use = enter & (stamp[slot] - ref == k * stride) & (slot < size)
-        ret = cast(ret + use * cast(np.float32(gamma ** k) * reward[slot]))
-        enter = use & (done[slot] < 0.5)
-    boot = (a + n_step * stride) % cap
-    has = enter & (stamp[boot] - ref == n_step * stride) & (boot < size)
-    nxt = stack(boot, stamp[boot], has)
+        use = enter & (stamp[:, col(k)] - ref == k * stride) & (slot(k) < size)
+        ret = cast(ret + use * cast(np.float32(gamma ** k)
+                                    * reward[:, col(k)]))
+        enter = use & (done[:, col(k)] < 0.5)
+    has = (enter & (stamp[:, col(n_step)] - ref == n_step * stride)
+           & (slot(n_step) < size))
+    nxt = stack(n_step, has)
     term = 1.0 - has.astype(np.float32)
-    return {"obs": obs, "action": np.asarray(ring["action"])[a],
+    return {"obs": obs, "action": np.asarray(rows["action"])[:, col(0)],
             "reward": ret, "next_obs": nxt, "terminated": term}
 
 

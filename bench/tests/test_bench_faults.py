@@ -93,6 +93,7 @@ def _unchanged_writeback(monkeypatch):
     ("amper-1m.learn", _altered_slab_draw, "draw"),
     ("per-1m.learn", _unchanged_learner, "delta"),
     ("per-1m.learn", _altered_slab_draw, "draw"),
+    ("per-1m.learn", _altered_slab_draw, "stacks"),
     ("amper-1m.draw", _altered_draw, "draw"),
     ("amper-1m.draw", _unchanged_writeback, "priorities"),
 ], ids=lambda v: getattr(v, "__name__", None))

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bench.drive import Keep
 from bench.reference import frames as ref_frames
 from bench.reference import sampler_amper_fr as amper
 from bench.reference import sampler_amper_fr_sharded as amper_sh
@@ -98,7 +99,7 @@ def _frame_buffer(n_step):
     cap, envs = 512, 4
     fs = FrameStore(history_len=4, frame_shape=(10, 10), stride=envs,
                     n_step=n_step, gamma=0.9)
-    rb = ReplayBuffer(cap, make_sampler("uniform", cap), frame_store=fs,
+    rb = ReplayBuffer(cap, make_sampler("per-sumtree", cap), frame_store=fs,
                       num_envs=envs)
     ex = {"frame": jnp.zeros((10, 10), jnp.uint8), "action": jnp.int32(0),
           "reward": jnp.float32(0), "done": jnp.float32(0),
@@ -116,11 +117,12 @@ def test_frame_stacks_match_materialize(n_step):
     rb, st, fs = _frame_buffer(n_step)
     idx = jnp.arange(0, 512, 3)
     got = jax.jit(rb.materialize)(st, idx)
-    ring = {k: np.asarray(v) for k, v in st.storage.items()}
+    # The reference reads only the rows the harness keeps around idx.
+    view = Keep(rb).view(st, idx)
     want = ref_frames.materialize(
-        ring, np.asarray(st.write_stamp), int(st.size), np.asarray(idx),
-        history_len=4, stride=fs.stride, n_step=n_step, gamma=0.9,
-        scale=fs.scale)
+        view["rows"], np.asarray(idx), int(view["size"]),
+        capacity=rb.capacity, history_len=4, stride=fs.stride,
+        n_step=n_step, gamma=0.9, scale=fs.scale)
     assert ref_frames.gap({k: np.asarray(v) for k, v in got.items()},
                           want) < 1e-6
     # Some stacks are cut by episode ends and the write head.
