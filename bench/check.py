@@ -27,6 +27,10 @@ reference (``bench/reference``) given the same inputs:
 With ``control=True`` the reference in bfloat16 takes the program's place
 (the lower precision a later change could be tempted to use); its
 numbers must fail at least one limit.
+
+The sampler and learner references are the modules the configuration
+names in ``law.reference_sampler`` and ``law.reference_learner``; the
+learner's interface is set out in ``bench/reference/td_loss.py``.
 """
 from __future__ import annotations
 
@@ -38,7 +42,6 @@ import jax
 import jax.numpy as jnp
 
 from bench.reference import frames as ref_frames
-from bench.reference import td_loss as ref_td
 from bench.reference import writeback as ref_wb
 
 BF16 = jnp.bfloat16
@@ -64,12 +67,12 @@ def _table(c, t: dict) -> dict:
 
 
 class Cell:
-    """Law parameters of one configuration, and its reference module."""
+    """Law parameters of one configuration, and its reference modules."""
 
-    def __init__(self, conf: dict, ref_sampler, shards: int):
+    def __init__(self, conf: dict, ref_sampler, ref_learner, shards: int):
         d = conf["dqn"]
         self.sampler = d["sampler"]
-        self.ref = ref_sampler
+        self.ref, self.learner = ref_sampler, ref_learner
         self.quant = self.sampler.startswith("amper")
         self.shards = shards
         self.cap = d["replay_size"]
@@ -155,12 +158,9 @@ def _writeback_number(c: Cell, before, after, idx, abs_td, live, control):
 
 
 def _learner_numbers(c: Cell, conf, rec, batch_ref, w_ref, control):
-    d = conf["dqn"]
     l0 = rec["learn0"]
     s = rec["slab"]
-    shape = batch_ref["obs"].shape
-    p0 = ref_td.init(rec["run_key"], d["history_len"], shape[1:3],
-                     d["hidden"], conf["law"]["n_actions"])
+    p0 = c.learner.learner_init(rec["run_key"], conf)
     init = sum(int(np.sum(_host(a) != _host(b))) for a, b in zip(
         jax.tree.leaves(p0), jax.tree.leaves(l0["params"])))
     # Back to slab order: flat row b*S + s -> [s, b].
@@ -170,13 +170,12 @@ def _learner_numbers(c: Cell, conf, rec, batch_ref, w_ref, control):
     b["action"] = b["action"].astype(jnp.int32)
     w = slab(w_ref if c.is_weights else np.ones(len(w_ref), np.float32))
     w = w.astype(jnp.float32)
-    gamma_n = d["gamma"] ** d["n_step"]
-    loss_r, td_r, par_r, g0 = ref_td.follow(
-        p0, b, w, gamma_n=gamma_n, lr=d["lr"], step0=int(l0["step0"]))
+    step0 = int(l0["step0"])
+    loss_r, td_r, par_r, g0 = c.learner.learner_follow(p0, b, w, conf,
+                                                       step0=step0)
     if control:
-        loss_p, td_p, par_p, _ = ref_td.follow(
-            p0, b, w, gamma_n=gamma_n, lr=d["lr"], step0=int(l0["step0"]),
-            dtype=BF16)
+        loss_p, td_p, par_p, _ = c.learner.learner_follow(
+            p0, b, w, conf, step0=step0, dtype=BF16)
     else:
         loss_p, td_p, par_p = l0["loss"], l0["td"], l0["out_params"]
     loss_r, td_r = _host(loss_r), _host(td_r)

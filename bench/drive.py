@@ -1,7 +1,8 @@
 """The two loops a traffic file can name (its ``loop`` key).
 
 ``service``: the async ``ReplayService`` as a user runs it, on a replay
-ring prefilled in set-up.  The window is one ``run``: it starts at the
+ring prefilled in set-up that each run hands on to the next, set-up's
+runs and then the window's.  The window is one ``run``: it starts at the
 learner's first update and ends when the last update's result is ready.
 ``run`` is bounded by update count, so set-up measures the rate on two
 short runs and sizes the window's run to ``--seconds``; the rate is taken
@@ -225,10 +226,10 @@ def _service(cell, key, seconds: float, trace_on: bool, profile):
 
     conf, traffic = cell.config, cell.traffic
     cfg = DQNConfig(**conf["dqn"])
-    srv = conf["service"]
+    # The configuration's service section as keyword arguments: a key
+    # ReplayService does not take, or one the harness sets, raises.
     svc = ReplayService(
-        cfg, num_actors=srv["num_actors"], chunk_len=srv["chunk_len"],
-        slab=srv["slab"], min_size=cfg.batch,
+        cfg, **conf["service"], min_size=cfg.batch,
         max_replay_ratio=cfg.batch * traffic["frames_per_update_per_row"],
         telemetry=obs.Telemetry(probe_every=0, profile=trace_on))
     dqn = svc.dqn
@@ -236,14 +237,14 @@ def _service(cell, key, seconds: float, trace_on: bool, profile):
     prefill = generator.make_prefill(
         dqn.replay, dqn.example_transition, cfg.num_envs, dqn.env.n_actions,
         traffic["prefill"])
-    # Set-up runs start from copies of the prefilled ring; the window's run
-    # is handed the ring itself, and the harness keeps no reference to it.
-    fill = {"state": jax.block_until_ready(prefill(k_fill)), "hand": False}
+    # One ring: the first run starts from the prefilled one, each later
+    # run from the final ring of the run before it (``RunResult.buffer``).
+    # Between runs the harness holds that ring alone, and no copy of it.
+    ring = [jax.block_until_ready(prefill(k_fill))]
     init = dqn.init
 
     def init_filled(k):
-        buf = fill.pop("state") if fill["hand"] else _copy(fill["state"])
-        return init(k)._replace(buffer=buf)
+        return init(k)._replace(buffer=ring.pop())
 
     svc.dqn = dqn._replace(init=init_filled)
     rec = ServiceRecorder(svc)
@@ -253,6 +254,7 @@ def _service(cell, key, seconds: float, trace_on: bool, profile):
     def run(k, calls, picks, annotate=False):
         rec.arm(calls, picks, annotate)
         res = svc.run(k, calls * slab)
+        ring.append(res.buffer)
         return res, calls * slab / (rec.t_end - rec.t_start)
 
     # Compile every stage (the copies the recorder makes included), then
@@ -266,7 +268,6 @@ def _service(cell, key, seconds: float, trace_on: bool, profile):
     picks = {0, *rng.choice(np.arange(1, calls),
                             size=min(traffic["check_slabs"] - 1, calls - 1),
                             replace=False).tolist()}
-    fill["hand"] = True
     t_setup = time.perf_counter()
     with profile():
         res, rate = run(run_keys[3], calls, picks, annotate=True)

@@ -8,12 +8,28 @@ The reference runs at ``Precision.HIGHEST`` in float32; the control runs
 every array in bfloat16.  ``follow`` applies the learner's steps to the
 same batches and returns each step's loss and TD errors, the parameters
 after the last step and the first step's gradient.
+
+A configuration names its learner reference in ``law.reference_learner``;
+the check and ``learner_mfu`` reach it only through these three functions,
+which every learner reference module defines, reading the sizes from the
+configuration (``dqn``: ``history_len``, ``hidden``, ``gamma``,
+``n_step``, ``lr``, ``batch``; ``law``: ``frame_hw``, ``n_actions``):
+
+``learner_init(key, conf) -> params``
+    the learner's initial weights from its run key;
+``learner_follow(params, batches, weights, conf, *, step0, dtype)
+-> (losses, td, params, grad0)``
+    ``follow`` with the discount ``gamma ** n_step`` and the step size;
+``update_flops(conf) -> int``
+    FLOPs of one learner update of the network this module checks.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from bench import work
 
 CONV_CHANNELS, K = 16, 3
 
@@ -101,3 +117,26 @@ def follow(params, batches, weights, *, gamma_n: float, lr: float,
     to32 = lambda t: jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), t)
     return (jnp.stack(losses).astype(jnp.float32),
             jnp.stack(tds).astype(jnp.float32), to32(params), to32(grad0))
+
+
+def learner_init(key, conf: dict):
+    d, law = conf["dqn"], conf["law"]
+    return init(key, d["history_len"], law["frame_hw"], d["hidden"],
+                law["n_actions"])
+
+
+def learner_follow(params, batches, weights, conf: dict, *, step0: int,
+                   dtype=jnp.float32):
+    d = conf["dqn"]
+    return follow(params, batches, weights, gamma_n=d["gamma"] ** d["n_step"],
+                  lr=d["lr"], step0=step0, dtype=dtype)
+
+
+def update_flops(conf: dict) -> int:
+    """The plain max target: no online forward on ``next_obs``."""
+    d, law = conf["dqn"], conf["law"]
+    h, w = law["frame_hw"]
+    fwd = work.conv_qnet_forward_flops(h, w, d["history_len"],
+                                       hidden=d["hidden"],
+                                       n_actions=law["n_actions"])
+    return work.qnet_update_flops(d["batch"], fwd, double=False)

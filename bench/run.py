@@ -78,10 +78,11 @@ def _profiling(on: bool, out: Path):
 
 
 class Ctx:
-    """What a per-layer reader gets."""
+    """What a per-layer reader gets; ``reference(name)`` loads a module of
+    ``bench/reference``."""
 
     def __init__(self, cell, result, tr, window, device_kind):
-        self.config = cell.config
+        self.config, self.reference = cell.config, cell.reference
         self.chips, self.result = cell.chips, result
         self.trace, self.window = tr, window
         self.device_kind = device_kind
@@ -94,6 +95,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     from bench import spec
 
     cell = spec.load_cell(workload, seed, overrides)
+    # The configuration names both references; a missing name is a KeyError.
+    ref_sampler, ref_learner = (
+        cell.reference(cell.config["law"][k])
+        for k in ("reference_sampler", "reference_learner"))
     devs = _devices(cell.chips, platform)
     import jax
 
@@ -139,8 +144,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                 metrics[m["name"]] = {"value": float(values[m["name"]]),
                                       "unit": m["unit"]}
     # The references run after the window and the memory reading.
-    law = check.Cell(cell.config, cell.reference(
-        cell.config["law"]["reference_sampler"]), rec["shards"])
+    law = check.Cell(cell.config, ref_sampler, ref_learner, rec["shards"])
     numbers = check.NUMBERS[loop](law, cell.config, rec, False)
     limits = cell.config["limits"][cell.traffic_name]
     correct, checks = check.judge(numbers, limits)
