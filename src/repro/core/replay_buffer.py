@@ -74,6 +74,7 @@ truncation-bootstrap distinction lives on the float path.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -347,9 +348,6 @@ class ReplayBuffer:
                 f"add_batch of {b} transitions exceeds capacity "
                 f"{self.capacity}: ring slots would collide within one write")
         idx = (state.pos + jnp.arange(b, dtype=jnp.int32)) % self.capacity
-        storage = self._constrain(jax.tree.map(
-            lambda buf, x: buf.at[idx].set(x), state.storage, transitions
-        ))
         sampler_state = self.sampler.update(
             state.sampler_state, idx,
             jnp.broadcast_to(state.max_priority, (b,))
@@ -360,19 +358,34 @@ class ReplayBuffer:
         stamps = lo + jnp.arange(b, dtype=jnp.int32)
         row_gen = state.add_gen + (stamps < lo).astype(jnp.int32)
         new_total = lo + jnp.int32(b)
+        storage, write_stamp, write_gen = self._constrain(self._write_rows(
+            (state.storage, state.write_stamp, state.write_gen),
+            (transitions, stamps, row_gen), state.pos, idx))
         return ReplayState(
             storage=storage,
             sampler_state=sampler_state,
             pos=(state.pos + b) % self.capacity,
             size=jnp.minimum(state.size + b, self.capacity),
             max_priority=state.max_priority,
-            write_stamp=self._constrain(state.write_stamp.at[idx].set(stamps)),
+            write_stamp=write_stamp,
             total_adds=new_total,
-            write_gen=self._constrain(
-                state.write_gen.at[idx].set(row_gen)),
+            write_gen=write_gen,
             add_gen=state.add_gen + (new_total < lo).astype(jnp.int32),
             nstep=state.nstep,
         )
+
+    def _write_rows(self, rings: Any, rows: Any, pos: jax.Array,
+                    idx: jax.Array) -> Any:
+        """Write ``rows`` (leading dim B) into the arc ``idx`` of ``rings``
+        (see :func:`_write_ring_arc`).  A ring sharded on its rows keeps
+        the scatter, which GSPMD splits by the shard owning each row."""
+        rows = jax.tree.map(
+            lambda buf, x: jnp.broadcast_to(
+                x, idx.shape + buf.shape[1:]).astype(buf.dtype),
+            rings, rows)
+        if self.storage_sharding is not None:
+            return _scatter_rows(rings, rows, idx)
+        return _write_ring_arc(rings, rows, pos, self.capacity)
 
     def add_batch(self, state: ReplayState, transitions: Any) -> ReplayState:
         """Store B transitions (leading dim B on every leaf) in one shot.
@@ -569,6 +582,64 @@ class ReplayBuffer:
             sampler_state=sampler_state,
             max_priority=jnp.maximum(state.max_priority, p_max),
         )
+
+
+def _scatter_rows(rings: Any, rows: Any, idx: jax.Array) -> Any:
+    return jax.tree.map(lambda buf, x: buf.at[idx].set(x), rings, rows)
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _write_ring_arc(rings: Any, rows: Any, pos: jax.Array,
+                    capacity: int) -> Any:
+    """Write ``rows`` (leading dim B) into the ring arc that starts at
+    ``pos`` of every leaf of ``rings``.
+
+    A scatter over a ring of frames lowers, on the TPU, only onto a
+    row-major operand, so XLA copies the whole ring into that layout and
+    back around every write.  An arc that does not cross the ring's end
+    is one contiguous window instead, written by a dynamic-update-slice
+    in the ring's own layout.  An arc that wraps is written one row at a
+    time: rare (one insert in capacity / B), and neither a scatter nor a
+    read of a ring window, either of which brings the relayout back.
+    Rings batched under ``vmap`` (``train_many``) keep the scatter: a
+    ``pos`` per ring would turn the branch into a select that computes
+    both writes and picks between whole rings.  Jitted, so that eager
+    callers reuse one compiled write per shape.
+    """
+    b = jax.tree.leaves(rows)[0].shape[0]
+
+    @jax.custom_batching.custom_vmap
+    def write(rings, rows, pos):
+        def arc(rings):
+            return jax.tree.map(
+                lambda buf, x: jax.lax.dynamic_update_slice_in_dim(
+                    buf, x, pos, 0), rings, rows)
+
+        def wrap(rings):
+            # The slot is recomputed from `pos`: indexing the arc's slot
+            # array here puts a ring copy in every iteration on the TPU.
+            def row(j, rings):
+                return jax.tree.map(
+                    lambda buf, x: jax.lax.dynamic_update_slice_in_dim(
+                        buf, jax.lax.dynamic_slice_in_dim(x, j, 1, 0),
+                        (pos + j) % capacity, 0), rings, rows)
+
+            with jax.named_scope("ring_write_wrap"):
+                return jax.lax.fori_loop(0, b, row, rings)
+
+        return jax.lax.cond(pos + b <= capacity, arc, wrap, rings)
+
+    @write.def_vmap
+    def write_batched(axis_size, in_batched, rings, rows, pos):
+        out = jax.vmap(
+            lambda r, x, p: _scatter_rows(
+                r, x, (p + jnp.arange(b)) % capacity),
+            in_axes=tuple(jax.tree.map(lambda on: 0 if on else None,
+                                       in_batched)),
+            axis_size=axis_size)(rings, rows, pos)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return write(rings, rows, pos)
 
 
 def dirty_arcs(capacity: int, base_pos: int, n_new: int) -> list[tuple[int, int]]:

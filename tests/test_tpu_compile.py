@@ -8,11 +8,19 @@ table size of 1M rows (``rank_select`` at the 500k slice one shard of a
 2M table holds), and check that the compiled program holds the kernel.
 Nothing runs: a described chip compiles, it does not execute.
 
+The replay ring's insert is compiled too, for ``breakout-amper-1m``'s
+ring of 1M MinAtar frames: a scatter over a ring of frames makes the
+chip's compiler copy the whole ring into another layout and back.
+
 The topology is described inside a module fixture, never at import, so
 each pytest worker collects the same tests and only the worker running
 this file loads the TPU compiler.
 """
+import functools
+import json
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +28,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.rl.dqn import DQNConfig, make_dqn
 
 N = 1_000_000
 M = 20
@@ -93,3 +102,30 @@ def test_amper_sample_compiles_for_v5e(one_chip):
                    _spec(one_chip, (M,), jnp.int32),
                    _spec(one_chip, (), jnp.int32),
                    _spec(one_chip, (2,), jnp.uint32))
+
+
+@pytest.mark.parametrize("rows", [16, 512])
+def test_ring_insert_writes_in_the_ring_layout_for_v5e(one_chip, rows):
+    """``add_block`` on ``breakout-amper-1m``'s ring of 1M 10x10 frames,
+    one 16-env step (the draw cell's insert) and a 32-step chunk (the
+    actor's): no scatter over the frame ring, and no temporary of half the
+    ring's size, which a copy of the ring into another layout needs."""
+    conf = json.loads((Path(__file__).parents[1] / "bench" / "configs"
+                       / "breakout-amper-1m.json").read_text())
+    dqn = make_dqn(DQNConfig(**conf["dqn"]))
+    ex = dqn.example_transition
+    state = jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype),
+        jax.eval_shape(dqn.replay.init, ex))
+    envs = dqn.cfg.num_envs
+    block = jax.tree.map(
+        lambda x: _spec(one_chip, (rows // envs, envs) + jnp.shape(x),
+                        jnp.asarray(x).dtype), ex)
+    c = jax.jit(functools.partial(dqn.replay.add_block, aggregated=True)
+                ).lower(state, block).compile()
+    ring = state.storage["frame"]
+    ring_type = re.escape(f"u8[{','.join(map(str, ring.shape))}]")
+    assert not re.search(rf"= {ring_type}\{{[^}}]*\}} scatter\(",
+                         c.as_text())
+    ring_bytes = ring.size * ring.dtype.itemsize
+    assert c.memory_analysis().temp_size_in_bytes < ring_bytes // 2

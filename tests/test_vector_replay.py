@@ -1,11 +1,13 @@
 """Vectorized actor pipeline: batched ring writes, VectorEnv, train_many,
 and the unified sampler registry."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.replay_buffer import ReplayBuffer
+from repro.core.replay_buffer import FrameStore, ReplayBuffer
 from repro.core.samplers import Sampler, available_samplers, make_sampler
 from repro.rl.dqn import DQNConfig, make_dqn
 from repro.rl.envs import CartPole, VectorEnv
@@ -102,6 +104,125 @@ def test_add_batch_larger_than_capacity_rejected():
     state = rb.init({"obs": jnp.zeros(3), "reward": jnp.float32(0)})
     with pytest.raises(ValueError, match="exceeds capacity"):
         rb.add_batch(state, _tr(5))
+
+
+# --- ring write: arc update vs the scatter it replaced -----------------------
+
+RING_CAP = 8
+N_STEP = 3
+
+
+class _ScatterRing(ReplayBuffer):
+    """The buffer with its ring rows written by one scatter over the arc,
+    as before the dynamic-update-slice: the reference."""
+
+    def _write_rows(self, rings, rows, pos, idx):
+        b = idx.shape[0]
+        return jax.tree.map(
+            lambda buf, x: buf.at[(pos + jnp.arange(b)) % self.capacity]
+            .set(x), rings, rows)
+
+
+def _ring_example(ring):
+    if ring == "frame":
+        return {"frame": jnp.zeros((3, 3), jnp.uint8),
+                "action": jnp.int32(0), "reward": jnp.float32(0),
+                "done": jnp.float32(0)}
+    return {"obs": jnp.zeros(3), "action": jnp.int32(0),
+            "reward": jnp.float32(0), "next_obs": jnp.zeros(3),
+            "done": jnp.float32(0)}
+
+
+def _random_like(key, x, lead=()):
+    x = jnp.asarray(x)
+    shape = lead + x.shape
+    if x.dtype == jnp.uint8:
+        return jax.random.randint(key, shape, 0, 256).astype(jnp.uint8)
+    if x.dtype == jnp.int32:
+        return jax.random.randint(key, shape, -2**31, 2**31 - 1, jnp.int32)
+    return jax.random.normal(key, shape, x.dtype)
+
+
+def _random_tree(key, tree, lead=()):
+    leaves, treedef = jax.tree.flatten(tree)
+    keys = jax.random.split(key, len(leaves))
+    return treedef.unflatten(
+        [_random_like(k, x, lead) for k, x in zip(keys, leaves)])
+
+
+@functools.lru_cache(maxsize=None)
+def _ring_writers(ring, path, b):
+    """(jitted new write, jitted reference write, full ring state, rows)
+    for one case; the state's ``pos`` is set by the test, so one compile
+    serves every position."""
+    ex = _ring_example(ring)
+    kw = {}
+    if ring == "frame":
+        kw["frame_store"] = FrameStore(history_len=2, frame_shape=(3, 3))
+    if path == "nstep":
+        kw.update(n_step=N_STEP, num_envs=b)
+    rbs = [cls(RING_CAP, make_sampler("amper-fr", RING_CAP, v_max=4.0,
+                                      min_csp=4), **kw)
+           for cls in (ReplayBuffer, _ScatterRing)]
+    k_ring, k_rows, k_nstep, k_misc = jax.random.split(jax.random.key(b), 4)
+    state = rbs[0].init(ex)
+    state = state._replace(
+        storage=_random_tree(k_ring, ex, (RING_CAP,)),
+        size=jnp.int32(RING_CAP),
+        write_stamp=_random_like(k_misc, jnp.int32(0), (RING_CAP,)),
+        write_gen=_random_like(jax.random.fold_in(k_misc, 1),
+                               jnp.int32(0), (RING_CAP,)),
+        total_adds=jnp.int32(2**31 - 2), add_gen=jnp.int32(5))
+    if path == "nstep":
+        nst = state.nstep
+        state = state._replace(nstep=nst._replace(
+            ring=_random_tree(k_nstep, ex, (N_STEP, b)),
+            count=jnp.int32(N_STEP)))
+    if path == "block":
+        # b rows as a [T, B] block: two timesteps where b splits.
+        t = 2 if b % 2 == 0 else 1
+        rows = _random_tree(k_rows, ex, (t, b // t))
+        writes = [jax.jit(functools.partial(rb.add_block, aggregated=True))
+                  for rb in rbs]
+    elif path == "vmap":
+        # Two rings written at once, as train_many's seeds are.
+        rows = _random_tree(k_rows, ex, (b,))
+        state = jax.tree.map(lambda x: jnp.stack([x, x]), state)
+        writes = [jax.jit(jax.vmap(rb.add_batch, in_axes=(0, None)))
+                  for rb in rbs]
+    else:
+        rows = _random_tree(k_rows, ex, (b,))
+        writes = [jax.jit(rb.add_batch) for rb in rbs]
+    return writes[0], writes[1], state, rows
+
+
+_RING_CASES = [(ring, path, b, pos)
+               for ring in ("frame", "flat")
+               for path in ("block", "batch", "nstep", "vmap")
+               if not (ring == "frame" and path == "nstep")
+               for b in (1, 3, RING_CAP)
+               for pos in sorted({0, RING_CAP // 2, RING_CAP - b,
+                                  (RING_CAP - b + 1) % RING_CAP,
+                                  RING_CAP - 1})]
+
+
+@pytest.mark.parametrize("ring,path,b,pos", _RING_CASES)
+def test_ring_write_matches_arc_scatter(ring, path, b, pos):
+    """Every leaf of the state after a write equals, bit for bit, the
+    state the scatter over the ring arc gives: arcs that fit, arcs that
+    cross the ring's end, and whole-ring writes, through add_block,
+    add_batch, the n-step add_batch path and add_batch under vmap (two
+    rings at different positions), on uint8 frame rings and flat float
+    rings."""
+    write, reference, state, rows = _ring_writers(ring, path, b)
+    state = state._replace(pos=jnp.int32(pos) if path != "vmap" else
+                           jnp.int32([pos, (pos + 3) % RING_CAP]))
+    got, want = write(state, rows), reference(state, rows)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert not np.array_equal(np.asarray(got.storage["reward"]),
+                              np.asarray(state.storage["reward"]))
 
 
 # --- VectorEnv ---------------------------------------------------------------
